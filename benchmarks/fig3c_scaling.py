@@ -47,6 +47,9 @@ def run(quick=False):
     # verified at 8 virtual devices by counting psum bytes in the HLO.
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # the child runs on 8 virtual CPU devices; it must never reach for an
+    # accelerator, which this (parent) process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     script = """
 import jax, jax.numpy as jnp, re

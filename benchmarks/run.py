@@ -11,6 +11,8 @@ import argparse
 import json
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -18,6 +20,7 @@ def main():
                     help="larger photon counts (slower)")
     args = ap.parse_args()
     quick = not args.full
+    enable_compile_cache()
 
     from benchmarks import (fig2_optimizations, fig3a_workgroup,
                             fig3b_devicelb, fig3c_scaling, fused, replay,

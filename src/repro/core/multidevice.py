@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import re
 import time
 from functools import partial
 from typing import Callable, Sequence
@@ -44,16 +45,23 @@ from repro.sources import PhotonSource, as_source
 from repro.telemetry.stats import RoundStats
 from repro.telemetry.trace import device_label
 
-# jax >= 0.6 exposes shard_map at the top level (vma type check); older
-# releases keep it in jax.experimental (replication rule check).  Either
-# check must be off: the while_loop carry mixes shard-varying (photon
-# counts) and replicated (volume) values.
-if hasattr(jax, "shard_map"):
-    _shard_map = partial(jax.shard_map, check_vma=False)
-else:  # pragma: no cover - exercised on jax < 0.6 only
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
+# the vma type check is off: the while_loop carry mixes shard-varying
+# (photon counts) and replicated (volume) values
+_shard_map = partial(jax.shard_map, check_vma=False)
 
-    _shard_map = partial(_exp_shard_map, check_rep=False)
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute", "collective-broadcast")
+
+
+def collective_kinds(hlo_text: str) -> dict[str, int]:
+    """Count the collective ops of compiled HLO text by kind (an async
+    pair counts once, at its ``-start``).  The mesh runners' contract is
+    ``{"all-reduce": n}``: the psum'd accumulators and nothing else."""
+    kinds: dict[str, int] = {}
+    pattern = r"\s(" + "|".join(COLLECTIVES) + r")(?:-start)?\("
+    for op in re.findall(pattern, hlo_text):
+        kinds[op] = kinds.get(op, 0) + 1
+    return kinds
 
 
 # ---------------------------------------------------------------------------

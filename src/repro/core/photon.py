@@ -161,6 +161,20 @@ def time_gate_bins(dep_t, tmax_ns, n_time_gates):
     return jnp.clip(g, 0, n_time_gates - 1)
 
 
+def normalize3(v):
+    """Scale ``(..., 3)`` vectors to unit length.
+
+    The squared norm is summed component by component, in a fixed order.
+    ``jnp.sum`` over the size-3 axis leaves the order to the compiler,
+    and on a TPU it follows the array layout: a vmapped batch
+    (``repro.scenarios``) and a single run then rounded the norm
+    differently and their trajectories drifted apart.
+    """
+    x, y, z = v[..., 0], v[..., 1], v[..., 2]
+    norm = jnp.sqrt(x * x + y * y + z * z)[..., None]
+    return v / jnp.maximum(norm, 1e-12)
+
+
 def _lookup_label(labels_flat, shape, ivox):
     nx, ny, nz = shape
     ix = jnp.clip(ivox[..., 0], 0, nx - 1)
@@ -218,8 +232,7 @@ def _hg_scatter(direc, g, u_cos, u_phi):
         axis=-1,
     )
     # renormalize to fight fp drift
-    norm = jnp.sqrt(jnp.sum(out * out, axis=-1, keepdims=True))
-    return out / jnp.maximum(norm, 1e-12)
+    return normalize3(out)
 
 
 def _fresnel(n_i, n_t, cos_i):
@@ -348,8 +361,7 @@ def step(state, labels_flat, media, shape, unitinmm, cfg: SimConfig) -> StepResu
         trans_tan = direc * (1.0 - axis_f) * eta[:, None]
         trans_nrm = axis_f * (sgn.astype(jnp.float32) * cos_t)[:, None]
         trans_dir = trans_tan + trans_nrm
-        tnorm = jnp.sqrt(jnp.sum(trans_dir * trans_dir, axis=-1, keepdims=True))
-        trans_dir = trans_dir / jnp.maximum(tnorm, 1e-12)
+        trans_dir = normalize3(trans_dir)
         bend = mismatch & do_reflect_flag
         trans_dir = jnp.where(bend[:, None], trans_dir, direc)
         new_dir_boundary = jnp.where(reflects[:, None], refl_dir, trans_dir)

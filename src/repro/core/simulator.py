@@ -137,6 +137,22 @@ def _as_id_pair(next_id):
     return jnp.asarray(next_id).astype(jnp.uint32), jnp.uint32(0)
 
 
+def lane_sum(x):
+    """Sum a float over the lane axis in a fixed pairwise order.
+
+    ``jnp.sum`` leaves the order to the compiler, which on a TPU follows
+    the array layout, so a vmapped batch (``repro.scenarios``) and a
+    single run rounded the escaped and launched weight differently.
+    Halving with elementwise adds fixes the order for any batching.
+    """
+    while x.shape[-1] > 1:
+        if x.shape[-1] % 2:
+            x = jnp.concatenate([x, jnp.zeros_like(x[..., :1])], axis=-1)
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 def _regenerate(state, remaining, launched_per_lane, next_id, quota,
                 source, seed, mode, shape, ppath=None, lane_ids=None):
     """Relaunch photons in dead lanes according to the workload mode.
@@ -192,7 +208,7 @@ def _regenerate(state, remaining, launched_per_lane, next_id, quota,
         remaining - n_relaunch,
         launched_per_lane + relaunch.astype(jnp.int32),
         (new_lo, new_hi),
-        jnp.sum(jnp.where(relaunch, w0, 0.0)),
+        lane_sum(jnp.where(relaunch, w0, 0.0)),
     )
     if ppath is not None:
         out = out + (jnp.where(relaunch[:, None], 0.0, ppath),)
@@ -439,8 +455,8 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
                 xy, xw = ph.exitance_bins(res.esc_pos, res.esc_w, shape)
                 ex_i = ex_i.at[k].set(xy)
                 ex_w = ex_w.at[k].set(xw)
-                esc = esc + jnp.sum(res.esc_w)
-                timed = timed + jnp.sum(res.timed_w)
+                esc = esc + lane_sum(res.esc_w)
+                timed = timed + lane_sum(res.timed_w)
                 if n_det:
                     pp, dw, dp = accumulate_capture(pp, dw, dp, res, gate,
                                                     det_geom, ntg)
@@ -516,8 +532,8 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
                 state, flu, exi, esc, timed = outs[:5]
                 energy = c.energy + flu
                 exitance = c.exitance + exi
-                escaped_w = c.escaped_w + jnp.sum(esc)
-                timed_out_w = c.timed_out_w + jnp.sum(timed)
+                escaped_w = c.escaped_w + lane_sum(esc)
+                timed_out_w = c.timed_out_w + lane_sum(timed)
                 cur = 5
                 if n_det:
                     ppath, dw, dp = outs[cur:cur + 3]
@@ -602,7 +618,7 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
         # weight still in flight when the max_steps cap fires is retired
         # deterministically, like the time gate — account it there so the
         # energy-balance residue only measures roulette statistics
-        capped_w = jnp.sum(jnp.where(final.state.alive, final.state.w, 0.0))
+        capped_w = lane_sum(jnp.where(final.state.alive, final.state.w, 0.0))
         if collect:
             # mirror the SimResult timed_out_w accounting (capped weight
             # retires deterministically) and fill the occupancy

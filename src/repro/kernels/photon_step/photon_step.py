@@ -40,10 +40,26 @@ The physics body is shared with the engine (repro.core.photon.step), so
 kernel trajectories are bit-identical to the oracle by construction; the
 kernel's contribution is the memory/layout architecture.
 
-Validated with interpret=True on CPU (tests/test_kernels_photon.py); on
-real TPU hardware the label gather (jnp.take) and fluence scatter-add
-lower via XLA gather/scatter — supported by Mosaic for rank-1 VMEM
-operands.
+Validated with interpret=True on CPU (tests/test_kernels_photon.py)
+only.  The kernel does NOT lower for a TPU yet.  Compiled for a TPU v5e
+(jax 0.9.0, libtpu 0.0.34; tests/test_tpu_compile.py, a strict xfail),
+Mosaic refuses it three times over:
+
+  1. the default ``block_lanes=256`` for the rank-1 int8 ``alive``
+     block: "rank 1 block shapes ... [must be] a multiple of the tiling
+     size (512 = 128 * (32 // 8))";
+  2. at 512 lanes, the 1-D ``jnp.take`` over the uint8 label volume and
+     the media table (``photon._lookup_label``, ``photon.step``): "Only
+     2D gather is supported";
+  3. the in-kernel scatter-adds into the revisited fluence, exitance,
+     TPSF and Jacobian blocks: "Unimplemented primitive in Pallas TPU
+     lowering ...: scatter-add".
+
+A ``jnp.where`` chain over the few media rows lowers; the label gather
+and the deposit scatters need a different design (HBM-resident labels
+and fluence moved by DMA, ROADMAP B2).  Until then ``engine="pallas"``
+fails at compile time on a TPU, out loud, and the jnp engine is the
+one that runs there.
 """
 
 from __future__ import annotations

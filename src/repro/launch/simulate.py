@@ -21,6 +21,7 @@ from repro.core import analysis as A
 from repro.core import simulator as S
 from repro.core import volume as V
 from repro.core.multidevice import ChunkScheduler, simulate_sharded
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def get_bench(name: str, size: int):
@@ -407,4 +408,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the command line gets the persistent compile cache; in-process
+    # callers of main() (tests, chip_smoke.py) keep their own setting
+    enable_compile_cache()
     main()

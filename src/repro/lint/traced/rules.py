@@ -24,8 +24,8 @@ _WIDE_DTYPES = frozenset({"float64", "complex128", "complex64",
 # host-transfer primitives: inside the round loop each one is a
 # device->host sync per iteration
 _CALLBACK_PRIMS = frozenset({"pure_callback", "debug_callback",
-                             "io_callback", "infeed", "outfeed",
-                             "device_get", "host_callback"})
+                             "debug_print", "io_callback", "infeed",
+                             "outfeed", "device_get", "host_callback"})
 
 # scatter modes whose result depends on the order duplicate indices
 # are applied in (add/min/max are order-dependent only under

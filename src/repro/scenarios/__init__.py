@@ -289,10 +289,14 @@ def _shard_batched_fn(vmapped, mesh, share_labels: bool, n_det: int):
     every stacked input across the mesh's first axis name.  Disjoint
     scenarios need no collectives — out_specs keep the scenario axis
     sharded and jit reassembles the global batch."""
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core.multidevice import _shard_map
 
+    # jax.make_mesh gives Explicit axes, under which slicing one scenario
+    # out of the sharded batch (simulate_many) is a type error; the same
+    # devices with Auto axes leave the placement to the compiler
+    mesh = Mesh(mesh.devices, mesh.axis_names)
     ax = mesh.axis_names[0]
     sspec = P(ax)
     in_specs = (P() if share_labels else sspec, sspec, sspec,

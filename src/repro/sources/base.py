@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import rng as xrng
+from repro.core.photon import normalize3
 
 # Domain-separation salt for the launch stream.  XORed into the master
 # seed so launch-time draws are decorrelated from the in-flight stream
@@ -143,8 +144,7 @@ def direction_from_axis(cost, phi, axis, e1, e2) -> jnp.ndarray:
         + (sint * jnp.sin(phi))[:, None] * e2
         + cost[:, None] * jnp.asarray(axis, jnp.float32)
     )
-    norm = jnp.sqrt(jnp.sum(d * d, axis=-1, keepdims=True))
-    return d / jnp.maximum(norm, 1e-12)
+    return normalize3(d)
 
 
 # ---------------------------------------------------------------------------

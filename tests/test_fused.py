@@ -92,7 +92,8 @@ def _seed_sim_fn(shape, unitinmm, cfg, n_lanes, mode="dynamic", source=None):
                 quota, source, seed, mode, shape)
             res = ph.step(state, labels_flat, media, shape, unitinmm, cfg)
             energy = c.energy.at[res.dep_idx].add(res.dep_w)
-            escaped_w = c.escaped_w + jnp.sum(res.esc_w)
+            # the engine sums lanes in a fixed order (S.lane_sum)
+            escaped_w = c.escaped_w + S.lane_sum(res.esc_w)
             z_exit = res.esc_pos[:, 2] < ph.Z_EXIT_FACE_VOX
             hit = (res.esc_w > 0) & z_exit
             ex = jnp.clip(jnp.floor(res.esc_pos[:, 0]).astype(jnp.int32),

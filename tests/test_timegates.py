@@ -105,7 +105,8 @@ def _pr2_fused_sim_fn(shape, unitinmm, cfg, n_lanes, mode="dynamic",
                 xy, xw = ph.exitance_bins(res.esc_pos, res.esc_w, shape)
                 ex_i = ex_i.at[k].set(xy)
                 ex_w = ex_w.at[k].set(xw)
-                esc = esc + jnp.sum(res.esc_w)
+                # the engine sums lanes in a fixed order (S.lane_sum)
+                esc = esc + S.lane_sum(res.esc_w)
                 return (res.state, dep_i, dep_w, ex_i, ex_w, esc)
 
             init = (

@@ -41,8 +41,7 @@ def _run(targets, *rule_ids, **kw):
 
 def test_dtype_flags_f64_in_trace():
     def make(ov):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             return jax.make_jaxpr(
                 lambda x: x.astype(jnp.float64).sum())(
                     jnp.ones(4, jnp.float32))
