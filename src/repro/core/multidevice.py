@@ -43,7 +43,7 @@ from repro.resilience import (DevicePool, DeviceSpec, FaultInjector,
                               harvest_result, validate_chunk)
 from repro.sources import PhotonSource, as_source
 from repro.telemetry.stats import RoundStats
-from repro.telemetry.trace import device_label
+from repro.telemetry.trace import default_tracer, device_label
 
 # the vma type check is off: the while_loop carry mixes shard-varying
 # (photon counts) and replicated (volume) values
@@ -120,8 +120,9 @@ def sharded_sim_fn(volume: Volume, cfg: SimConfig, n_lanes: int,
             # subsets, so the cross-shard reduction is the same psum as
             # every other accumulator
             summed["stats"] = res.stats
-        for a in ax:
-            summed = {k: jax.lax.psum(v, a) for k, v in summed.items()}
+        with jax.named_scope("psum"):
+            for a in ax:
+                summed = {k: jax.lax.psum(v, a) for k, v in summed.items()}
         # steps and the record buffer/cursor stay per-shard (rank-1 /
         # row-blocked so they concatenate over the mesh)
         return SimResult(steps=res.steps[None], det_rec=res.det_rec,
@@ -151,7 +152,7 @@ def simulate_sharded(volume: Volume, cfg: SimConfig, n_photons: int,
                      source: PhotonSource | Source | None = None,
                      mode: str = "dynamic", engine: str = "jnp",
                      detectors=None, record_detected: int = 0,
-                     id_offset: int = 0) -> SimResult:
+                     id_offset: int = 0, tracer=None) -> SimResult:
     """Run one distributed simulation over the mesh's photon axes.
 
     ``id_offset`` shifts the whole campaign's global photon-id range (a
@@ -159,6 +160,11 @@ def simulate_sharded(volume: Volume, cfg: SimConfig, n_photons: int,
     chunk start here); per-shard offsets are split into uint32 lo/hi
     words so shards beyond the 2**32 boundary keep disjoint RNG
     streams.
+
+    Returns once the result is ready; ``tracer`` (else the process's
+    ``default_tracer``) records the same ``simulate`` span with
+    ``simulate.compile`` and ``simulate.execute`` children as
+    ``simulator.simulate``.
     """
     n_shards = int(np.prod([mesh.shape[a] for a in axis_names]))
     if partition is None:
@@ -175,21 +181,27 @@ def simulate_sharded(volume: Volume, cfg: SimConfig, n_photons: int,
     offsets_lo = (offsets & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     offsets_hi = (offsets >> np.uint64(32)).astype(np.uint32)
 
-    fn = sharded_sim_fn(volume, cfg, n_lanes, mesh, axis_names, mode, source,
-                        engine, detectors, record_detected)
-    shard_sharding = NamedSharding(mesh, P(axis_names))
-    repl = NamedSharding(mesh, P())
-    dev_counts = jax.device_put(jnp.asarray(counts), shard_sharding)
-    dev_off_lo = jax.device_put(jnp.asarray(offsets_lo), shard_sharding)
-    dev_off_hi = jax.device_put(jnp.asarray(offsets_hi), shard_sharding)
-    return fn(
-        jax.device_put(volume.labels.reshape(-1), repl),
-        jax.device_put(volume.media, repl),
-        dev_counts,
-        dev_off_lo,
-        dev_off_hi,
-        jnp.uint32(seed),
-    )
+    tracer = default_tracer() if tracer is None else tracer
+    with tracer.span("simulate", device="mesh", engine=engine,
+                     photons=n_photons):
+        shard_sharding = NamedSharding(mesh, P(axis_names))
+        repl = NamedSharding(mesh, P())
+        args = (
+            jax.device_put(volume.labels.reshape(-1), repl),
+            jax.device_put(volume.media, repl),
+            jax.device_put(jnp.asarray(counts), shard_sharding),
+            jax.device_put(jnp.asarray(offsets_lo), shard_sharding),
+            jax.device_put(jnp.asarray(offsets_hi), shard_sharding),
+            jnp.uint32(seed),
+        )
+        with tracer.span("simulate.compile", engine=engine):
+            fn = sharded_sim_fn(volume, cfg, n_lanes, mesh, axis_names, mode,
+                                source, engine, detectors, record_detected)
+            # the call below finds this executable in the jit's caches
+            fn.lower(*args).compile()
+        with tracer.span("simulate.execute", engine=engine,
+                         photons=n_photons, lanes=n_lanes):
+            return jax.block_until_ready(fn(*args))
 
 
 def sharded_replay_fn(volume: Volume, cfg: SimConfig, detectors, mesh: Mesh,
@@ -234,8 +246,9 @@ def sharded_replay_fn(volume: Volume, cfg: SimConfig, detectors, mesh: Mesh,
     def worker(labels_flat, media, id_lo, id_hi, jac_col, active, seed):
         jac, w_exit, gate, rdet = raw(labels_flat, media, id_lo, id_hi,
                                       jac_col, active, seed)
-        for a in ax:
-            jac = jax.lax.psum(jac, a)
+        with jax.named_scope("psum"):
+            for a in ax:
+                jac = jax.lax.psum(jac, a)
         return jac, w_exit, gate, rdet
 
     pspec = P(ax)

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import rng as xrng
@@ -255,6 +256,7 @@ def _fresnel(n_i, n_t, cos_i):
     return jnp.clip(r, 0.0, 1.0), cos_t, tir
 
 
+@jax.named_scope("step")
 def step(state, labels_flat, media, shape, unitinmm, cfg: SimConfig) -> StepResult:
     """Advance every lane by one segment.
 
@@ -267,8 +269,9 @@ def step(state, labels_flat, media, shape, unitinmm, cfg: SimConfig) -> StepResu
     unitinmm = jnp.float32(unitinmm)
     nx, ny, nz = shape
 
-    label, _ = _lookup_label(labels_flat, shape, ivox)
-    props = jnp.take(media, label.astype(jnp.int32), axis=0)  # (N, 4)
+    with jax.named_scope("step.lookup"):
+        label, _ = _lookup_label(labels_flat, shape, ivox)
+        props = jnp.take(media, label.astype(jnp.int32), axis=0)  # (N, 4)
     mua = props[:, 0] * unitinmm
     mus = props[:, 1] * unitinmm
     g = props[:, 2]
@@ -327,59 +330,62 @@ def step(state, labels_flat, media, shape, unitinmm, cfg: SimConfig) -> StepResu
     is_scatter = alive & ~hits_wall
 
     # --- BOUNDARY: next voxel, Fresnel, escape ---
-    axis_onehot = jnp.eye(3, dtype=jnp.int32)[cross_axis]  # (N, 3)
-    axis_f = axis_onehot.astype(jnp.float32)
-    dir_axis = jnp.sum(direc * axis_f, axis=-1)
-    sgn = jnp.sign(dir_axis).astype(jnp.int32)
-    next_vox = ivox + axis_onehot * sgn[:, None]
-    oob = (
-        (next_vox[:, 0] < 0) | (next_vox[:, 0] >= nx)
-        | (next_vox[:, 1] < 0) | (next_vox[:, 1] >= ny)
-        | (next_vox[:, 2] < 0) | (next_vox[:, 2] >= nz)
-    )
-    next_label, _ = _lookup_label(labels_flat, shape, next_vox)
-    next_label = jnp.where(oob, 0, next_label)
-    n_next = jnp.take(media, next_label.astype(jnp.int32), axis=0)[:, 3]
-    mismatch = jnp.abs(n_next - n_cur) > 1e-6
-    cos_i = jnp.abs(dir_axis)
-
-    if cfg.specialize and not cfg.do_reflect:
-        # B1-style specialized kernel: no Fresnel/refraction in the graph.
-        reflects = jnp.zeros_like(hits_wall)
-        new_dir_boundary = direc
-    else:
-        refl_r, cos_t, _tir = _fresnel(n_cur, n_next, cos_i)
-        do_reflect_flag = (
-            True if (cfg.specialize and cfg.do_reflect)
-            else jnp.bool_(cfg.do_reflect)
+    with jax.named_scope("step.boundary"):
+        axis_onehot = jnp.eye(3, dtype=jnp.int32)[cross_axis]  # (N, 3)
+        axis_f = axis_onehot.astype(jnp.float32)
+        dir_axis = jnp.sum(direc * axis_f, axis=-1)
+        sgn = jnp.sign(dir_axis).astype(jnp.int32)
+        next_vox = ivox + axis_onehot * sgn[:, None]
+        oob = (
+            (next_vox[:, 0] < 0) | (next_vox[:, 0] >= nx)
+            | (next_vox[:, 1] < 0) | (next_vox[:, 1] >= ny)
+            | (next_vox[:, 2] < 0) | (next_vox[:, 2] >= nz)
         )
-        reflects = hits_wall & mismatch & (u_fres < refl_r) & do_reflect_flag
-        # reflected direction: flip the crossing-axis component
-        refl_dir = direc * (1.0 - 2.0 * axis_f)
-        # transmitted (refracted): scale tangentials, set normal cosine
-        eta = n_cur / jnp.maximum(n_next, 1e-6)
-        trans_tan = direc * (1.0 - axis_f) * eta[:, None]
-        trans_nrm = axis_f * (sgn.astype(jnp.float32) * cos_t)[:, None]
-        trans_dir = trans_tan + trans_nrm
-        trans_dir = normalize3(trans_dir)
-        bend = mismatch & do_reflect_flag
-        trans_dir = jnp.where(bend[:, None], trans_dir, direc)
-        new_dir_boundary = jnp.where(reflects[:, None], refl_dir, trans_dir)
+        next_label, _ = _lookup_label(labels_flat, shape, next_vox)
+        next_label = jnp.where(oob, 0, next_label)
+        n_next = jnp.take(media, next_label.astype(jnp.int32), axis=0)[:, 3]
+        mismatch = jnp.abs(n_next - n_cur) > 1e-6
+        cos_i = jnp.abs(dir_axis)
 
-    crossing = alive & hits_wall
-    new_dir = jnp.where(
-        is_scatter[:, None],
-        scat_dir,
-        jnp.where(crossing[:, None], new_dir_boundary, direc),
-    )
+        if cfg.specialize and not cfg.do_reflect:
+            # B1-style specialized kernel: no Fresnel/refraction in the graph.
+            reflects = jnp.zeros_like(hits_wall)
+            new_dir_boundary = direc
+        else:
+            refl_r, cos_t, _tir = _fresnel(n_cur, n_next, cos_i)
+            do_reflect_flag = (
+                True if (cfg.specialize and cfg.do_reflect)
+                else jnp.bool_(cfg.do_reflect)
+            )
+            reflects = (hits_wall & mismatch & (u_fres < refl_r)
+                        & do_reflect_flag)
+            # reflected direction: flip the crossing-axis component
+            refl_dir = direc * (1.0 - 2.0 * axis_f)
+            # transmitted (refracted): scale tangentials, set normal cosine
+            eta = n_cur / jnp.maximum(n_next, 1e-6)
+            trans_tan = direc * (1.0 - axis_f) * eta[:, None]
+            trans_nrm = axis_f * (sgn.astype(jnp.float32) * cos_t)[:, None]
+            trans_dir = trans_tan + trans_nrm
+            trans_dir = normalize3(trans_dir)
+            bend = mismatch & do_reflect_flag
+            trans_dir = jnp.where(bend[:, None], trans_dir, direc)
+            new_dir_boundary = jnp.where(reflects[:, None], refl_dir,
+                                         trans_dir)
 
-    escapes = crossing & ~reflects & (oob | (next_label == 0))
-    esc_w = jnp.where(escapes, w_after, 0.0)
-    esc_pos = new_pos
+        crossing = alive & hits_wall
+        new_dir = jnp.where(
+            is_scatter[:, None],
+            scat_dir,
+            jnp.where(crossing[:, None], new_dir_boundary, direc),
+        )
 
-    # advance the authoritative voxel index on transmitting crossings
-    advances = crossing & ~reflects & ~escapes
-    new_ivox = jnp.where(advances[:, None], next_vox, ivox)
+        escapes = crossing & ~reflects & (oob | (next_label == 0))
+        esc_w = jnp.where(escapes, w_after, 0.0)
+        esc_pos = new_pos
+
+        # advance the authoritative voxel index on transmitting crossings
+        advances = crossing & ~reflects & ~escapes
+        new_ivox = jnp.where(advances[:, None], next_vox, ivox)
 
     # --- ROULETTE + time gate ---
     alive_after = alive & ~escapes

@@ -48,6 +48,7 @@ from repro.detectors import (Detector, accumulate_capture, as_detectors,
                              validate_detectors)
 from repro.sources import PhotonSource, as_source
 from repro.telemetry.stats import RoundStats
+from repro.telemetry.trace import default_tracer
 
 ENGINES = ("jnp", "pallas")
 
@@ -218,6 +219,7 @@ def _regenerate(state, remaining, launched_per_lane, next_id, quota,
     return out
 
 
+@jax.named_scope("regen")
 def _maybe_regenerate(state, remaining, launched_per_lane, next_id, quota,
                       source, seed, mode, shape, ppath=None, lane_ids=None):
     """Regenerate only when some lane will actually relaunch.
@@ -417,6 +419,7 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
                 lane_segments=jnp.float32(0.0),
                 deposited_w=jnp.float32(0.0), escaped_w=jnp.float32(0.0),
                 timed_out_w=jnp.float32(0.0), detected_w=jnp.float32(0.0),
+                dep_live=jnp.float32(0.0), exit_live=jnp.float32(0.0),
             ) if collect else ()),
         )
 
@@ -484,6 +487,7 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
             )
             return jax.lax.fori_loop(0, K, seg, init)
 
+        @jax.named_scope("record")
         def append_records(c: _Carry, lane_ids, capd, capg):
             """Append this round's captures to the fixed-capacity id
             buffer: slots come from a prefix sum over captured lanes
@@ -522,7 +526,7 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
                     quota, source, seed, mode, shape)
                 ppath = c.ppath
             capd = capg = None
-            live = dep_sum = det_new = None
+            live = dep_sum = det_new = dep_live = exit_live = None
             if engine == "pallas":
                 outs = photon_step_pallas(
                     labels_flat, media, state, shape, unitinmm, cfg, K,
@@ -546,19 +550,26 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
                     capd, capg = outs[cur:cur + 2]
                     cur += 2
                 if collect:
-                    # the kernel's (n_lanes, 2) stats block: col 0 counts
-                    # segments entered alive, col 1 sums deposited weight
+                    # the kernel's (n_lanes, 4) stats block: col 0 counts
+                    # segments entered alive, col 1 sums deposited weight,
+                    # cols 2 and 3 count segments with a deposit and with
+                    # an exitance weight > 0
                     st_block = outs[cur]
                     live = jnp.sum(st_block[:, 0])
                     dep_sum = jnp.sum(st_block[:, 1])
+                    dep_live = jnp.sum(st_block[:, 2])
+                    exit_live = jnp.sum(st_block[:, 3])
                     det_new = (jnp.sum(dw) if n_det
                                else jnp.float32(0.0))
             else:
                 (state, ppath, dep_i, dep_w, ex_i, ex_w, esc, timed,
                  dw, dp, capd, capg, live) = round_jnp(state, ppath)
-                energy = c.energy.at[dep_i.reshape(-1)].add(dep_w.reshape(-1))
-                exitance = c.exitance.at[ex_i.reshape(-1)].add(
-                    ex_w.reshape(-1))
+                with jax.named_scope("flush.fluence"):
+                    energy = c.energy.at[dep_i.reshape(-1)].add(
+                        dep_w.reshape(-1))
+                with jax.named_scope("flush.exitance"):
+                    exitance = c.exitance.at[ex_i.reshape(-1)].add(
+                        ex_w.reshape(-1))
                 escaped_w = c.escaped_w + esc
                 timed_out_w = c.timed_out_w + timed
                 det_w = c.det_w + dw
@@ -566,6 +577,10 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
                 if collect:
                     dep_sum = jnp.sum(dep_w)
                     det_new = jnp.sum(dw)
+                    # the (K, n_lanes) updates the two flushes scatter
+                    # that carry weight
+                    dep_live = jnp.sum(dep_w > 0, dtype=jnp.float32)
+                    exit_live = jnp.sum(ex_w > 0, dtype=jnp.float32)
             if collect:
                 # uint32 difference is exact across a low-word wrap, and
                 # per-round relaunch counts stay far below 2**31
@@ -584,6 +599,8 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
                     escaped_w=escaped_w,
                     timed_out_w=timed_out_w,
                     detected_w=s.detected_w + det_new,
+                    dep_live=s.dep_live + dep_live,
+                    exit_live=s.exit_live + exit_live,
                 )
             else:
                 stats = ()
@@ -675,7 +692,8 @@ def simulate(volume: Volume, cfg: SimConfig, n_photons: int,
              mode: str = "dynamic", engine: str = "jnp",
              block_lanes: int = 256,
              interpret: bool | None = None,
-             detectors=None, record_detected: int = 0) -> SimResult:
+             detectors=None, record_detected: int = 0,
+             tracer=None) -> SimResult:
     """Convenience one-shot simulation on the current default device.
 
     ``source`` accepts any registered source type (repro.sources), the
@@ -686,16 +704,27 @@ def simulate(volume: Volume, cfg: SimConfig, n_photons: int,
     ``detectors`` (repro.detectors spec) enables TPSF recording on the
     z=0 face (DESIGN.md §time-resolved); ``record_detected`` sets the
     detected-photon id buffer capacity for replay (DESIGN.md §replay).
+
+    Returns once the result is ready.  ``tracer`` (else
+    :func:`repro.telemetry.default_tracer`) records a ``simulate`` span
+    with two children: ``simulate.compile`` (building the jit, tracing,
+    lowering, and compiling or loading from the persistent cache) and
+    ``simulate.execute`` (dispatch of that executable to its outputs
+    ready).
     """
-    sim_fn = make_simulator(volume, cfg, n_lanes, mode, source, engine,
-                            block_lanes, interpret, detectors,
-                            record_detected)
-    return sim_fn(
-        volume.labels.reshape(-1),
-        volume.media,
-        n_photons,
-        seed,
-    )
+    tracer = default_tracer() if tracer is None else tracer
+    args = (volume.labels.reshape(-1), volume.media, n_photons, seed)
+    with tracer.span("simulate", device=jax.devices()[0], engine=engine,
+                     photons=n_photons):
+        with tracer.span("simulate.compile", engine=engine):
+            sim_fn = make_simulator(volume, cfg, n_lanes, mode, source,
+                                    engine, block_lanes, interpret,
+                                    detectors, record_detected)
+            # the call below finds this executable in the jit's caches
+            sim_fn.lower(*args).compile()
+        with tracer.span("simulate.execute", engine=engine,
+                         photons=n_photons, lanes=n_lanes):
+            return jax.block_until_ready(sim_fn(*args))
 
 
 # ---------------------------------------------------------------------------

@@ -37,6 +37,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -135,6 +136,7 @@ def detector_bins(esc_pos, esc_w, det_geom):
     return det_idx, jnp.where(hit_any, esc_w, 0.0)
 
 
+@jax.named_scope("capture")
 def accumulate_capture(pp, dw, dp, res, gate, det_geom, ntg):
     """One segment of detector bookkeeping, shared by the jnp engine,
     the Pallas kernel and the ref oracle so all three capture
@@ -159,6 +161,7 @@ def accumulate_capture(pp, dw, dp, res, gate, det_geom, ntg):
     return pp, dw, dp
 
 
+@jax.named_scope("capture")
 def update_capture(cap_det, cap_gate, res, gate, det_geom):
     """One segment of detected-photon id bookkeeping (DESIGN.md §replay).
 

@@ -40,7 +40,7 @@ def photon_steps(labels_flat, media, state, shape, unitinmm, cfg: SimConfig,
     ``(ppath, det_w_flat, det_ppath)`` when detectors are configured,
     plus per-lane ``(cap_det, cap_gate)`` capture records when
     ``record`` is set, plus the ``(nvox * jac_cols,)`` replay-Jacobian
-    accumulator when ``jac_cols > 0``, plus the trailing ``(n, 2)``
+    accumulator when ``jac_cols > 0``, plus the trailing ``(n, 4)``
     telemetry counter block when ``stats`` is set (see
     ``photon_step_pallas``).
 

@@ -211,10 +211,14 @@ def _kernel(labels_ref, media_ref, *refs,
             out = out + (jac,)
         if stats:
             # telemetry counters (DESIGN.md §observability): col 0 counts
-            # segments entered alive, col 1 sums deposited weight; pure
-            # extra reductions, never read back by any physics value
+            # segments entered alive, col 1 sums deposited weight, cols 2
+            # and 3 count segments with a deposit and with an exitance
+            # weight > 0; pure extra reductions, never read back by any
+            # physics value
             stbl = stbl + jnp.stack(
-                [st.alive.astype(jnp.float32), res.dep_w], axis=1)
+                [st.alive.astype(jnp.float32), res.dep_w,
+                 (res.dep_w > 0).astype(jnp.float32),
+                 (xw > 0).astype(jnp.float32)], axis=1)
             out = out + (stbl,)
         return out
 
@@ -230,7 +234,7 @@ def _kernel(labels_ref, media_ref, *refs,
     if jac_cols:
         init = init + (jnp.zeros_like(jac_ref),)
     if stats:
-        init = init + (jnp.zeros((n, 2), jnp.float32),)
+        init = init + (jnp.zeros((n, 4), jnp.float32),)
     final = jax.lax.fori_loop(0, n_steps, body, init)
     state, flu_add, exi_add, esc, timed = final[:5]
 
@@ -287,9 +291,11 @@ def photon_step_pallas(labels_flat, media, state: ph.PhotonState,
     the exit-weight scale and fixed Jacobian column of the record being
     replayed; DESIGN.md §replay).
 
-    ``stats=True`` appends one more lane-blocked ``(n, 2)`` float32
+    ``stats=True`` appends one more lane-blocked ``(n, 4)`` float32
     output (always last): column 0 counts segments each lane entered
-    alive, column 1 sums the lane's deposited weight over the round —
+    alive, column 1 sums the lane's deposited weight over the round,
+    columns 2 and 3 count the lane's segments whose deposit and whose
+    z=0-face exitance weight are > 0 —
     the in-kernel half of the ``SimConfig.collect_stats`` telemetry
     counters (DESIGN.md §observability).  The block is accumulated
     alongside the physics carries and written per lane block; it never
@@ -402,9 +408,9 @@ def photon_step_pallas(labels_flat, media, state: ph.PhotonState,
         out_specs += [full_spec(nvox * jac_cols)]              # revisited
     if stats:
         out_shapes += [
-            jax.ShapeDtypeStruct((n, 2), jnp.float32),   # telemetry block
+            jax.ShapeDtypeStruct((n, 4), jnp.float32),   # telemetry block
         ]
-        out_specs += [lane_spec((2,))]
+        out_specs += [lane_spec((4,))]
 
     kernel = functools.partial(
         _kernel, shape=shape, unitinmm=unitinmm, cfg=cfg, n_steps=n_steps,

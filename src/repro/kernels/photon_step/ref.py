@@ -30,9 +30,10 @@ def photon_steps_ref(labels_flat, media, state: ph.PhotonState,
     ``(ppath, det_w_flat, det_ppath)`` when detectors are configured,
     plus ``(cap_det, cap_gate)`` per-lane capture records when
     ``record`` is set, plus the ``(nvox * jac_cols,)`` replay-Jacobian
-    accumulator when ``jac_cols > 0``, plus the trailing ``(n, 2)``
-    telemetry counter block (segments-entered-alive, deposited weight)
-    when ``stats`` is set (same contract as ``photon_step_pallas``)."""
+    accumulator when ``jac_cols > 0``, plus the trailing ``(n, 4)``
+    telemetry counter block (segments entered alive, deposited weight,
+    segments with a deposit, segments with an exitance weight) when
+    ``stats`` is set (same contract as ``photon_step_pallas``)."""
     if (ppath is None) != (det_geom is None):
         raise ValueError("ppath and det_geom must be given together")
     jac_cols = int(jac_cols)
@@ -84,7 +85,9 @@ def photon_steps_ref(labels_flat, media, state: ph.PhotonState,
             out = out + (jac,)
         if stats:
             stbl = stbl + jnp.stack(
-                [st.alive.astype(jnp.float32), res.dep_w], axis=1)
+                [st.alive.astype(jnp.float32), res.dep_w,
+                 (res.dep_w > 0).astype(jnp.float32),
+                 (xw > 0).astype(jnp.float32)], axis=1)
             out = out + (stbl,)
         return out
 
@@ -100,5 +103,5 @@ def photon_steps_ref(labels_flat, media, state: ph.PhotonState,
     if jac_cols:
         init = init + (jnp.zeros((nvox * jac_cols,), jnp.float32),)
     if stats:
-        init = init + (jnp.zeros((n, 2), jnp.float32),)
+        init = init + (jnp.zeros((n, 4), jnp.float32),)
     return jax.lax.fori_loop(0, n_steps, body, init)

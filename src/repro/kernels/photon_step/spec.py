@@ -127,7 +127,7 @@ def estimate_vmem_bytes(nvox: int, nxy: int, ntg: int = 1,
     if jac_cols:
         lane += 2 * 4                        # jac_w + jac_col inputs
     if stats:
-        lane += 2 * 4                        # (n, 2) f32 telemetry block
+        lane += 4 * 4                        # (n, 4) f32 telemetry block
     return vol + block_lanes * lane
 
 

@@ -33,64 +33,54 @@ def get_bench(name: str, size: int):
     raise ValueError(name)
 
 
-def _run_scenarios(args, ap, tracer, sinks):
+def _run_scenarios(args, ap, tracer, t_start_ns):
     """--scenarios: batched multi-scenario execution (DESIGN.md §batching)."""
-    spec = args.scenarios
-    if spec.startswith("@"):
-        with open(spec[1:]) as f:
-            spec = f.read()
-    entries = json.loads(spec)
-    if not isinstance(entries, list) or not entries:
-        ap.error("--scenarios expects a non-empty JSON list of scenario "
-                 "dicts (or @file.json holding one)")
-    scenarios = [SC.Scenario.from_dict(e) for e in entries]
-    mesh = None
-    if args.devices == "all" and len(jax.devices()) > 1:
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    with tracer.span("cli.prepare", start_ns=t_start_ns):
+        spec = args.scenarios
+        if spec.startswith("@"):
+            with open(spec[1:]) as f:
+                spec = f.read()
+        entries = json.loads(spec)
+        if not isinstance(entries, list) or not entries:
+            ap.error("--scenarios expects a non-empty JSON list of "
+                     "scenario dicts (or @file.json holding one)")
+        scenarios = [SC.Scenario.from_dict(e) for e in entries]
+        mesh = None
+        if args.devices == "all" and len(jax.devices()) > 1:
+            mesh = jax.make_mesh((len(jax.devices()),), ("data",))
     cache = SC.default_cache()
     t0 = time.time()
     results = SC.simulate_many(scenarios, n_lanes=args.lanes,
                                engine=args.engine, mesh=mesh, cache=cache,
                                tracer=tracer)
-    jax.block_until_ready(results)
     dt = time.time() - t0
 
-    total_photons = sum(sc.n_photons for sc in scenarios)
-    keys = {SC.group_key(sc, args.lanes, engine=args.engine)
-            for sc in scenarios}
-    sharded = f" over {mesh.size} devices" if mesh is not None else ""
-    print(f"scenarios: {len(scenarios)} in {dt:.2f}s "
-          f"({len(scenarios)/dt:.2f} scenarios/s, "
-          f"{total_photons/dt/1e3:.2f} photons/ms total), "
-          f"{len(keys)} config shape(s){sharded}")
-    st = cache.stats()
-    print(f"compile cache: {st['hits']} hits / {st['misses']} misses "
-          f"(hit rate {st['hit_rate']:.2f}), {st['entries']} entries, "
-          f"{st['evictions']} evictions")
-    for i, (sc, res) in enumerate(zip(scenarios, results)):
-        bal = A.energy_balance(res)
-        line = (f"  scenario {i}: {sc.n_photons} photons seed={sc.seed} "
-                f"absorbed={bal['absorbed']:.1f} "
-                f"escaped={bal['escaped']:.1f} "
-                f"residue={bal['residue_frac']:.2e}")
-        if sc.detectors:
-            line += f" det_w={np.asarray(res.det_w).sum():.3f}"
-        print(line)
-    if tracer is not None:
-        tracer.counter("scenarios_per_s", len(scenarios) / dt,
-                       engine=args.engine)
-        tracer.counter("photons_per_s", total_photons / dt,
-                       engine=args.engine)
-        if args.trace_out:
-            path = tracer.save_chrome_trace(args.trace_out)
-            print(f"trace timeline: {path} "
-                  f"({len(tracer.events)} spans; open in chrome://tracing)")
-        for sink in sinks:
-            sink.close()
+    with tracer.span("cli.summarize"):
+        total_photons = sum(sc.n_photons for sc in scenarios)
+        keys = {SC.group_key(sc, args.lanes, engine=args.engine)
+                for sc in scenarios}
+        sharded = f" over {mesh.size} devices" if mesh is not None else ""
+        print(f"scenarios: {len(scenarios)} in {dt:.2f}s "
+              f"({len(scenarios)/dt:.2f} scenarios/s, "
+              f"{total_photons/dt/1e3:.2f} photons/ms total), "
+              f"{len(keys)} config shape(s){sharded}")
+        st = cache.stats()
+        print(f"compile cache: {st['hits']} hits / {st['misses']} misses "
+              f"(hit rate {st['hit_rate']:.2f}), {st['entries']} entries, "
+              f"{st['evictions']} evictions")
+        for i, (sc, res) in enumerate(zip(scenarios, results)):
+            bal = A.energy_balance(res)
+            line = (f"  scenario {i}: {sc.n_photons} photons seed={sc.seed} "
+                    f"absorbed={bal['absorbed']:.1f} "
+                    f"escaped={bal['escaped']:.1f} "
+                    f"residue={bal['residue_frac']:.2e}")
+            if sc.detectors:
+                line += f" det_w={np.asarray(res.det_w).sum():.3f}"
+            print(line)
     return results
 
 
-def main(argv=None):
+def _parse(argv):
     ap = argparse.ArgumentParser()
     ap.add_argument("--bench", default="B1", choices=["B1", "B2", "B2a"])
     ap.add_argument("--photons", type=int, default=100_000)
@@ -224,26 +214,56 @@ def main(argv=None):
                          f"--{flag.replace('_', '-')} (scenario dicts "
                          f"carry their own per-scenario config)")
 
-    source = json.loads(args.source) if args.source else None
-    detectors = D.as_detectors(
-        json.loads(args.detectors)) if args.detectors else None
-    vol, cfg = get_bench(args.bench, args.size)
-    if args.steps_per_round != 1:
-        cfg = dataclasses.replace(cfg, steps_per_round=args.steps_per_round)
-    if args.time_gates != 1:
-        cfg = dataclasses.replace(cfg, n_time_gates=args.time_gates)
-    if args.tmax_ns is not None:
-        cfg = dataclasses.replace(cfg, tmax_ns=args.tmax_ns)
-    if args.collect_stats:
-        cfg = dataclasses.replace(cfg, collect_stats=True)
+    return ap, args
 
+
+def main(argv=None):
+    """One call of the command line.  Its spans, in ``--trace-out``'s
+    tracer or else the process's ``telemetry.default_tracer``: a
+    ``cli.main`` root with ``cli.prepare`` (argument parsing, volume,
+    source, detectors), the entry point's own spans (``simulate`` with
+    ``simulate.compile`` and ``simulate.execute``, or the ``chunk`` and
+    ``scenarios.*`` spans), ``cli.summarize`` (energy balance, fluence,
+    gates, detector and record summaries) and the replay's spans."""
+    t_start_ns = time.time_ns()
+    ap, args = _parse(argv)
     sinks = []
     if args.metrics_out:
         sinks.append(T.JsonlSink(args.metrics_out))
-    tracer = (T.Tracer(sinks=sinks)
-              if (args.trace_out or sinks) else None)
-    if args.scenarios:
-        return _run_scenarios(args, ap, tracer, sinks)
+    tracer = (T.Tracer(sinks=sinks) if (args.trace_out or sinks)
+              else T.default_tracer())
+    with tracer.span("cli.main", start_ns=t_start_ns, bench=args.bench,
+                     photons=args.photons):
+        if args.scenarios:
+            res = _run_scenarios(args, ap, tracer, t_start_ns)
+        else:
+            res = _run(args, tracer, t_start_ns)
+    if args.trace_out:
+        path = tracer.save_chrome_trace(args.trace_out)
+        print(f"trace timeline: {path} "
+              f"({len(tracer.events)} spans; open in chrome://tracing)")
+    for sink in sinks:
+        sink.close()
+    return res
+
+
+def _run(args, tracer, t_start_ns):
+    """The forward run (one device, the mesh, or chunked), its
+    summaries and the optional replay."""
+    with tracer.span("cli.prepare", start_ns=t_start_ns):
+        source = json.loads(args.source) if args.source else None
+        detectors = D.as_detectors(
+            json.loads(args.detectors)) if args.detectors else None
+        vol, cfg = get_bench(args.bench, args.size)
+        if args.steps_per_round != 1:
+            cfg = dataclasses.replace(cfg,
+                                      steps_per_round=args.steps_per_round)
+        if args.time_gates != 1:
+            cfg = dataclasses.replace(cfg, n_time_gates=args.time_gates)
+        if args.tmax_ns is not None:
+            cfg = dataclasses.replace(cfg, tmax_ns=args.tmax_ns)
+        if args.collect_stats:
+            cfg = dataclasses.replace(cfg, collect_stats=True)
     lanes = args.lanes
     if args.autotune:
         lanes, timings = S.autotune_lanes(vol, cfg, n_pilot=args.photons // 10,
@@ -292,31 +312,55 @@ def main(argv=None):
                   f"{c['checkpoints']} checkpoints")
     elif args.devices == "all" and len(jax.devices()) > 1:
         mesh = jax.make_mesh((len(jax.devices()),), ("data",))
-        span = tracer.span("simulate", device="mesh", engine=args.engine,
-                           photons=args.photons) if tracer else None
         res = simulate_sharded(vol, cfg, args.photons, mesh,
                                n_lanes=lanes, seed=args.seed, source=source,
                                engine=args.engine, detectors=detectors,
-                               record_detected=args.save_detected)
-        if span is not None:
-            jax.block_until_ready(res)
-            span.end()
+                               record_detected=args.save_detected,
+                               tracer=tracer)
     else:
-        span = tracer.span("simulate", device=jax.devices()[0],
-                           engine=args.engine,
-                           photons=args.photons) if tracer else None
         res = S.simulate(vol, cfg, args.photons, lanes, args.seed,
                          source=source, engine=args.engine,
                          detectors=detectors,
-                         record_detected=args.save_detected)
-        if span is not None:
-            jax.block_until_ready(res)
-            span.end()
+                         record_detected=args.save_detected, tracer=tracer)
     jax.block_until_ready(res)
     dt = time.time() - t0
 
+    with tracer.span("cli.summarize"):
+        recs = _summarize(args, tracer, res, vol, cfg, detectors, dt)
+    if args.replay and recs.shape[0]:
+        from repro.replay import replay_jacobian
+
+        t0 = time.time()
+        rep = replay_jacobian(vol, cfg, recs, detectors, source=source,
+                              seed=args.seed, n_lanes=lanes,
+                              engine=args.replay_engine,
+                              gate_resolved=args.replay_gate_resolved,
+                              mesh=mesh, tracer=tracer)
+        dt = time.time() - t0
+        ok = int((rep.replayed_det == rep.det).sum())
+        sharded = f" over {mesh.size} devices" if mesh is not None else ""
+        print(f"replay[{args.replay_engine}]: {rep.n_records} photons "
+              f"in {dt:.2f}s ({rep.n_records/dt/1e3:.2f} photons/ms)"
+              f"{sharded}, {ok}/{rep.n_records} detector-exact")
+        jac = rep.jacobian
+        med = A.jacobian_medium_sums(jac, vol)
+        gated = jac if jac.ndim == 4 else jac.sum(axis=-1)
+        for i, d in enumerate(detectors):
+            nz = int(np.sum(gated[..., i] > 0))
+            print(f"  J[det {i}]: sum={gated[..., i].sum():.3e} "
+                  f"(weight*mm), nonzero voxels={nz}, per-medium "
+                  f"{np.array_str(med[i], precision=3)}")
+        if jac.ndim == 5:
+            per_gate = jac.sum(axis=(0, 1, 2, 3))
+            print(f"  gate-resolved: {jac.shape[-1]} gates, "
+                  f"peak gate {int(per_gate.argmax())}")
+    return res
+
+
+def _summarize(args, tracer, res, vol, cfg, detectors, dt):
+    """Print the forward run's summaries; returns its detected-photon
+    records (empty unless ``--save-detected``)."""
     bal = A.energy_balance(res)
-    fwd_pps = args.photons / dt
     print(f"{args.bench}: {args.photons} photons in {dt:.2f}s "
           f"({args.photons/dt/1e3:.2f} photons/ms)")
     print(f"energy balance: absorbed={bal['absorbed']:.1f} "
@@ -337,10 +381,9 @@ def main(argv=None):
               f"{sd['lane_occupancy']:.1%} "
               f"({sd['live_segments']:.3g}/{sd['lane_segments']:.3g} "
               f"lane-segments live)")
-        if tracer is not None:
-            for k, v in sd.items():
-                tracer.counter(f"round_stats.{k}", v, bench=args.bench,
-                               engine=args.engine)
+        for k, v in sd.items():
+            tracer.counter(f"round_stats.{k}", v, bench=args.bench,
+                           engine=args.engine)
     phi = A.fluence_cw(res, vol)
     print(f"fluence: max={float(np.max(np.asarray(phi))):.3e} "
           f"nonzero voxels={int(np.sum(np.asarray(phi) > 0))}")
@@ -358,53 +401,20 @@ def main(argv=None):
                   f"weight={tot[i]:.3f} tpsf-peak@{peak:.3f} ns")
         print("mean partial pathlengths (mm/medium):")
         print(np.array_str(A.detector_mean_ppath(res), precision=2))
-    if args.save_detected:
-        from repro.replay import detected_records, replay_jacobian
+    if not args.save_detected:
+        return np.zeros((0, 4), np.uint32)
+    from repro.replay import detected_records
 
-        recs = detected_records(res)
-        overflow = int(np.asarray(res.det_rec_overflow))
-        print(f"detected-photon records: {recs.shape[0]} "
-              f"(overflow: {overflow})")
-        if overflow > 0:
-            print(f"WARNING: {overflow} detector captures were dropped "
-                  f"from the id buffer (capacity {args.save_detected} per "
-                  f"simulation unit) — det_w still counts them, but "
-                  f"replay will miss them; raise --save-detected")
-        if args.replay and recs.shape[0]:
-            t0 = time.time()
-            rep = replay_jacobian(vol, cfg, recs, detectors, source=source,
-                                  seed=args.seed, n_lanes=lanes,
-                                  engine=args.replay_engine,
-                                  gate_resolved=args.replay_gate_resolved,
-                                  mesh=mesh, tracer=tracer)
-            dt = time.time() - t0
-            ok = int((rep.replayed_det == rep.det).sum())
-            sharded = f" over {mesh.size} devices" if mesh is not None else ""
-            print(f"replay[{args.replay_engine}]: {rep.n_records} photons "
-                  f"in {dt:.2f}s ({rep.n_records/dt/1e3:.2f} photons/ms)"
-                  f"{sharded}, {ok}/{rep.n_records} detector-exact")
-            jac = rep.jacobian
-            med = A.jacobian_medium_sums(jac, vol)
-            gated = jac if jac.ndim == 4 else jac.sum(axis=-1)
-            for i, d in enumerate(detectors):
-                nz = int(np.sum(gated[..., i] > 0))
-                print(f"  J[det {i}]: sum={gated[..., i].sum():.3e} "
-                      f"(weight*mm), nonzero voxels={nz}, per-medium "
-                      f"{np.array_str(med[i], precision=3)}")
-            if jac.ndim == 5:
-                per_gate = jac.sum(axis=(0, 1, 2, 3))
-                print(f"  gate-resolved: {jac.shape[-1]} gates, "
-                      f"peak gate {int(per_gate.argmax())}")
-    if tracer is not None:
-        tracer.counter("photons_per_s", fwd_pps,
-                       bench=args.bench, engine=args.engine)
-        if args.trace_out:
-            path = tracer.save_chrome_trace(args.trace_out)
-            print(f"trace timeline: {path} "
-                  f"({len(tracer.events)} spans; open in chrome://tracing)")
-        for sink in sinks:
-            sink.close()
-    return res
+    recs = detected_records(res)
+    overflow = int(np.asarray(res.det_rec_overflow))
+    print(f"detected-photon records: {recs.shape[0]} "
+          f"(overflow: {overflow})")
+    if overflow > 0:
+        print(f"WARNING: {overflow} detector captures were dropped "
+              f"from the id buffer (capacity {args.save_detected} per "
+              f"simulation unit) — det_w still counts them, but "
+              f"replay will miss them; raise --save-detected")
+    return recs
 
 
 if __name__ == "__main__":

@@ -20,7 +20,8 @@ Executables live in an explicit :class:`CompileCache` keyed by the
 traced config shape (group key + batch size + labels sharing + mesh),
 so new scenarios of a known shape reuse compiled code; hit/miss/eviction
 counters surface through ``repro.telemetry`` (``scenarios.cache.*``
-counters, ``scenarios.compile`` / ``scenarios.batch`` spans).
+counters; ``scenarios.simulate_many`` spans with ``scenarios.batch``,
+``scenarios.compile`` and ``scenarios.execute`` children).
 
 Bit-identity: JAX's while_loop batching rule select-freezes finished
 batch elements, and the staged-source path replays the identical op
@@ -52,6 +53,7 @@ from repro.core.simulator import ENGINES, SimResult, build_sim_fn
 from repro.core.volume import SimConfig, Volume
 from repro.detectors import (as_detectors, det_geometry, validate_detectors)
 from repro.sources import StagedSource, as_source, stage_source
+from repro.telemetry.trace import default_tracer
 
 __all__ = [
     "CompileCache",
@@ -408,12 +410,16 @@ def simulate_many(scenarios, *, n_lanes: int = 1024, mode: str = "dynamic",
     when None) — new scenario *values* of a known shape never recompile.
     ``mesh`` shards each group's scenario axis across the mesh's first
     axis (zero-photon padding rounds the batch up to the device count).
-    ``tracer`` records one ``scenarios.batch`` span per group execution,
-    one ``scenarios.compile`` span per cache miss, and
-    ``scenarios.cache.{hit,miss,evictions,hit_rate}`` counters.
+    ``tracer`` (else :func:`repro.telemetry.default_tracer`) records a
+    ``scenarios.simulate_many`` span whose children are
+    ``scenarios.prepare``, one ``scenarios.batch`` per group (holding a
+    ``scenarios.compile`` span per cache miss, compilation alone, and a
+    ``scenarios.execute`` span, dispatch to outputs ready) and
+    ``scenarios.split``; and ``scenarios.cache.{hit,miss,evictions,
+    hit_rate}`` counters.
 
     Returns per-scenario ``SimResult``\\ s in input order, each
-    bit-identical to its own :func:`simulate_one`.
+    bit-identical to its own :func:`simulate_one`, once they are ready.
     """
     if engine not in ENGINES:
         raise ValueError(f"unknown engine: {engine!r} (choose from {ENGINES})")
@@ -421,56 +427,66 @@ def simulate_many(scenarios, *, n_lanes: int = 1024, mode: str = "dynamic",
     if not scenarios:
         return []
     cache = default_cache() if cache is None else cache
-    preps = [_prepare(i, sc) for i, sc in enumerate(scenarios)]
-    groups: OrderedDict = OrderedDict()
-    for p in preps:
-        gkey = _group_key(p, n_lanes, mode, engine, block_lanes, interpret)
-        groups.setdefault(gkey, []).append(p)
-    n_dev = 1
-    if mesh is not None:
-        n_dev = int(np.prod([mesh.shape[a] for a in mesh.axis_names[:1]]))
-    out: list = [None] * len(scenarios)
-    evictions0 = cache.evictions
-    for gkey, members in groups.items():
-        share = _share_labels(members)
-        pad = (-len(members)) % n_dev
-        s_pad = len(members) + pad
-        key = (gkey, s_pad, share, _mesh_signature(mesh))
-        fn = cache.get(key)
-        hit = fn is not None
-        if not hit:
-            raw = _raw_batched_fn(members[0], n_lanes, mode, engine,
-                                  block_lanes, interpret, share)
-            if mesh is not None:
-                raw = _shard_batched_fn(raw, mesh, share,
-                                        len(members[0].dets))
-            fn = jax.jit(raw)
-            cache.put(key, fn)
-        args = _stack_group(members, pad, share)
-        total_photons = int(sum(m.sc.n_photons for m in members))
-        bspan = cspan = None
-        if tracer is not None:
+    tracer = default_tracer() if tracer is None else tracer
+    with tracer.span("scenarios.simulate_many", engine=engine,
+                     scenarios=len(scenarios),
+                     photons=int(sum(sc.n_photons for sc in scenarios))):
+        with tracer.span("scenarios.prepare", scenarios=len(scenarios)):
+            preps = [_prepare(i, sc) for i, sc in enumerate(scenarios)]
+            groups: OrderedDict = OrderedDict()
+            for p in preps:
+                gkey = _group_key(p, n_lanes, mode, engine, block_lanes,
+                                  interpret)
+                groups.setdefault(gkey, []).append(p)
+        n_dev = 1
+        if mesh is not None:
+            n_dev = int(np.prod([mesh.shape[a]
+                                 for a in mesh.axis_names[:1]]))
+        batches = []
+        evictions0 = cache.evictions
+        for gkey, members in groups.items():
+            share = _share_labels(members)
+            pad = (-len(members)) % n_dev
+            s_pad = len(members) + pad
+            key = (gkey, s_pad, share, _mesh_signature(mesh))
+            fn = cache.get(key)
+            hit = fn is not None
+            total_photons = int(sum(m.sc.n_photons for m in members))
             tracer.counter("scenarios.cache." + ("hit" if hit else "miss"),
                            1, engine=engine, scenarios=len(members))
-            bspan = tracer.span("scenarios.batch", device=(
-                "mesh" if mesh is not None else None), engine=engine,
-                photons=total_photons, scenarios=len(members),
-                cache_hit=hit)
-            if not hit:
-                cspan = tracer.span("scenarios.compile", engine=engine,
-                                    scenarios=s_pad)
-        res = fn(*args)
-        jax.block_until_ready(res)
-        if cspan is not None:
-            cspan.end()
-        if bspan is not None:
-            bspan.end()
-        for j, m in enumerate(members):
-            out[m.idx] = jax.tree_util.tree_map(lambda a, j=j: a[j], res)
-    if tracer is not None:
-        st = cache.stats()
-        tracer.counter("scenarios.cache.hit_rate", st["hit_rate"],
-                       engine=engine)
-        tracer.counter("scenarios.cache.evictions",
-                       cache.evictions - evictions0, engine=engine)
+            with tracer.span("scenarios.batch", device=(
+                    "mesh" if mesh is not None else None), engine=engine,
+                    photons=total_photons, scenarios=len(members),
+                    cache_hit=hit):
+                args = _stack_group(members, pad, share)
+                if not hit:
+                    with tracer.span("scenarios.compile", engine=engine,
+                                     scenarios=s_pad):
+                        raw = _raw_batched_fn(members[0], n_lanes, mode,
+                                              engine, block_lanes,
+                                              interpret, share)
+                        if mesh is not None:
+                            raw = _shard_batched_fn(raw, mesh, share,
+                                                    len(members[0].dets))
+                        fn = jax.jit(raw)
+                        # the call below finds this executable in the
+                        # jit's caches
+                        fn.lower(*args).compile()
+                    cache.put(key, fn)
+                with tracer.span("scenarios.execute", engine=engine,
+                                 photons=total_photons, lanes=n_lanes,
+                                 scenarios=len(members)):
+                    res = jax.block_until_ready(fn(*args))
+            batches.append((members, res))
+        out: list = [None] * len(scenarios)
+        with tracer.span("scenarios.split", scenarios=len(scenarios)):
+            for members, res in batches:
+                for j, m in enumerate(members):
+                    out[m.idx] = jax.tree_util.tree_map(
+                        lambda a, j=j: a[j], res)
+            jax.block_until_ready(out)
+    st = cache.stats()
+    tracer.counter("scenarios.cache.hit_rate", st["hit_rate"], engine=engine)
+    tracer.counter("scenarios.cache.evictions",
+                   cache.evictions - evictions0, engine=engine)
     return out

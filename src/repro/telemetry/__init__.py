@@ -5,8 +5,11 @@ Three layers (DESIGN.md §observability):
   * :class:`RoundStats` — per-round physics counters accumulated inside
     both engines when ``SimConfig.collect_stats`` is set, returned on
     ``SimResult.stats``;
-  * :class:`Tracer` / :func:`chrome_trace` — host-side span timeline of
-    chunk/batch dispatches, exportable as Chrome ``trace_event`` JSON;
+  * :class:`Tracer` / :func:`chrome_trace` — host-side span tree of the
+    entry points' calls (preparation, compile, execution) and of
+    chunk/batch dispatches, on the profiler's clock, exportable as
+    Chrome ``trace_event`` JSON; :func:`default_tracer` is the
+    process-wide, always-on one;
   * :class:`MetricsSink` backends (:class:`InMemorySink`,
     :class:`JsonlSink`) — structured event consumers, wired to the CLI's
     ``--metrics-out``.
@@ -21,6 +24,7 @@ from repro.telemetry.trace import (
     SpanEvent,
     Tracer,
     chrome_trace,
+    default_tracer,
     device_label,
     device_samples,
     fit_device_models,
@@ -35,6 +39,7 @@ __all__ = [
     "SpanEvent",
     "Tracer",
     "chrome_trace",
+    "default_tracer",
     "device_label",
     "device_samples",
     "fit_device_models",

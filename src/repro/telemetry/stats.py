@@ -55,6 +55,14 @@ class RoundStats(NamedTuple):
     #                             SimResult.timed_out_w
     detected_w: np.ndarray      # () float32 weight captured by detector
     #                             disks; reconciles with sum(det_w)
+    dep_live: np.ndarray = np.float32(0.0)   # () float32 lane-segments
+    #                             whose deposit weight is > 0: the updates
+    #                             of the fluence flush that carry weight
+    exit_live: np.ndarray = np.float32(0.0)  # () float32 lane-segments
+    #                             whose z=0-face exitance weight is > 0:
+    #                             the exitance flush's useful updates
+    #                             (defaulted so 9-field checkpoint vectors
+    #                             still load)
 
     def lane_occupancy(self) -> float:
         """Live-lane fraction of all executed lane-segments, in [0, 1]."""

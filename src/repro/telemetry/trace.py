@@ -6,10 +6,20 @@ per-device throughput and feeding it back into work assignment
 (Sec. 2.4); cross-vendor portability studies (PAPERS.md) likewise lean
 on per-kernel event timing.  This module gives the schedulers that
 instrument: a :class:`Tracer` wraps every chunk / batch dispatch in a
-monotonic-clock span tagged with device, engine and photon count, and
+span tagged with device, engine and photon count, and
 the recorded timeline exports as Chrome ``trace_event`` JSON
 (chrome://tracing, Perfetto) or streams to any
 :class:`repro.telemetry.MetricsSink`.
+
+Spans form a tree: a span opened while another is open as a ``with``
+block records it as its parent, and every span records the id of its
+root call.  Times are on ``time.time_ns()``'s clock, the clock the JAX
+profiler's trace is aligned to, and every span is also a
+``jax.profiler.TraceAnnotation``, so in a profile the program's spans
+land in the host plane beside the device's operations.  The entry
+points record into the tracer they are given, or else into the
+process-wide :func:`default_tracer`, which is always on and keeps a
+bounded ring of recent spans.
 
 The span records double as *measured throughput samples*:
 :func:`fit_device_models` turns a recorded (or re-loaded) timeline into
@@ -18,21 +28,29 @@ distinct sizes give the paper's full ``T = a*n + T0`` pilot fit via
 ``fit_pilot``; equal-size chunks fall back to a throughput-only model
 (``t0 = 0``).  That closes the loop the ROADMAP's "true heterogeneous
 execution" item is blocked on: dispatch, measure, refit, re-partition.
-
-For real profiler runs, ``Tracer(profiler=True)`` additionally brackets
-every span in a ``jax.profiler.TraceAnnotation`` so the host-side spans
-line up with XLA's device timeline in TensorBoard/Perfetto captures.
 """
 
 from __future__ import annotations
 
+import collections
+import contextvars
 import dataclasses
+import itertools
 import json
 import time
 from pathlib import Path
 from typing import Sequence
 
+from jax.profiler import TraceAnnotation
+
 from repro.telemetry.sinks import MetricsSink
+
+DEFAULT_RING = 4096  # spans the process tracer keeps
+
+_span_ids = itertools.count(1)
+# the innermost span open as a ``with`` block in this thread / context
+_open_span: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_open_span", default=None)
 
 
 def device_label(device) -> str:
@@ -50,10 +68,13 @@ class SpanEvent:
 
     name: str
     device: str               # device_label() string
-    t0: float                 # monotonic start, seconds
+    t0: float                 # start, seconds on time.time_ns()'s clock
     dur: float                # duration, seconds
     engine: str | None = None
     args: dict = dataclasses.field(default_factory=dict)
+    span_id: int = 0
+    parent_id: int | None = None  # None: a root call
+    root_id: int = 0              # span_id of the root call
 
     @property
     def photons_per_s(self) -> float | None:
@@ -65,7 +86,8 @@ class SpanEvent:
     def to_dict(self) -> dict:
         out = {"type": "span", "name": self.name, "device": self.device,
                "t0": self.t0, "dur_s": self.dur, "engine": self.engine,
-               **self.args}
+               "span_id": self.span_id, "parent_id": self.parent_id,
+               "root_id": self.root_id, **self.args}
         pps = self.photons_per_s
         if pps is not None:
             out["photons_per_s"] = pps
@@ -74,43 +96,50 @@ class SpanEvent:
 
 class _Span:
     """Open span handle; ``end()`` (or exiting the ``with`` block) seals
-    it into the tracer's event list and sinks."""
+    it into the tracer's event list and sinks.  Only a span entered as a
+    ``with`` block becomes the parent of spans opened inside it; an
+    explicitly ended handle (an async dispatch) is a leaf."""
 
     def __init__(self, tracer: "Tracer", name: str, device: str,
-                 engine: str | None, args: dict):
+                 engine: str | None, args: dict, start_ns: int | None):
         self._tracer = tracer
-        self.event = SpanEvent(name=name, device=device, t0=0.0, dur=0.0,
-                               engine=engine, args=args)
-        self._annotation = None
-        if tracer.profiler:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._annotation = TraceAnnotation(
-                    f"{name}[{device}]")
-                self._annotation.__enter__()
-            except Exception:  # pragma: no cover - profiler unavailable
-                self._annotation = None
-        self.event.t0 = time.monotonic()  # reprolint: disable=REP201 - span timing is this module's job
+        parent = _open_span.get()
+        span_id = next(_span_ids)
+        self.event = SpanEvent(
+            name=name, device=device, t0=0.0, dur=0.0, engine=engine,
+            args=args, span_id=span_id,
+            parent_id=None if parent is None else parent.span_id,
+            root_id=span_id if parent is None else parent.root_id)
+        self._token = None
+        self._annotation = TraceAnnotation(name)
+        self._annotation.__enter__()
+        self._start_ns = (time.time_ns() if start_ns is None  # reprolint: disable=REP201 - span timing is this module's job
+                          else int(start_ns))
 
     def end(self, **extra_args) -> SpanEvent:
-        self.event.dur = time.monotonic() - self.event.t0  # reprolint: disable=REP201 - span timing is this module's job
-        if self._annotation is not None:
-            self._annotation.__exit__(None, None, None)
-            self._annotation = None
+        end_ns = time.time_ns()  # reprolint: disable=REP201 - span timing is this module's job
+        self._close_annotation()
+        self.event.t0 = self._start_ns / 1e9
+        self.event.dur = (end_ns - self._start_ns) / 1e9
         self.event.args.update(extra_args)
         self._tracer._record(self.event)
         return self.event
 
+    def _close_annotation(self):
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+
     def __enter__(self):
+        self._token = _open_span.set(self.event)
         return self
 
     def __exit__(self, exc_type, *exc):
+        _open_span.reset(self._token)
         if exc_type is None:
             self.end()
-        elif self._annotation is not None:  # pragma: no cover - error path
-            self._annotation.__exit__(None, None, None)
-            self._annotation = None
+        else:  # pragma: no cover - error path
+            self._close_annotation()
 
 
 class Tracer:
@@ -121,20 +150,24 @@ class Tracer:
     result array is ready); it is also a context manager for the
     synchronous case.  All completed events are kept in ``events`` (for
     in-process consumers like :func:`fit_device_models`) and forwarded
-    to every sink as flat dicts.
+    to every sink as flat dicts.  ``max_events`` bounds ``events`` to a
+    ring of the most recent ones (``None``: keep all).
     """
 
     def __init__(self, sinks: Sequence[MetricsSink] = (),
-                 profiler: bool = False):
+                 max_events: int | None = None):
         self.sinks = list(sinks)
-        self.profiler = bool(profiler)
-        self.events: list[SpanEvent] = []
+        self.events: collections.deque[SpanEvent] = collections.deque(
+            maxlen=max_events)
 
     # -- spans -------------------------------------------------------------
 
     def span(self, name: str, device=None, engine: str | None = None,
-             **args) -> _Span:
-        return _Span(self, name, device_label(device), engine, dict(args))
+             start_ns: int | None = None, **args) -> _Span:
+        """Open a span now, or at ``start_ns`` (``time.time_ns()``) for
+        work that began before the tracer was chosen."""
+        return _Span(self, name, device_label(device), engine, dict(args),
+                     start_ns)
 
     def _record(self, event: SpanEvent) -> None:
         self.events.append(event)
@@ -161,6 +194,16 @@ class Tracer:
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(json.dumps(self.chrome_trace(), indent=1) + "\n")
         return path
+
+
+_DEFAULT_TRACER = Tracer(max_events=DEFAULT_RING)
+
+
+def default_tracer() -> Tracer:
+    """The process-wide tracer the entry points record into when no
+    ``tracer`` is passed: always on, no sinks, the last
+    :data:`DEFAULT_RING` spans."""
+    return _DEFAULT_TRACER
 
 
 # ---------------------------------------------------------------------------
@@ -276,8 +319,8 @@ def fit_device_models(events_or_trace, name: str | None = None) -> dict:
     from repro.core.loadbalance import DeviceModel, model_from_samples
 
     events = events_or_trace
-    if not (isinstance(events, (list, tuple)) and
-            all(isinstance(e, SpanEvent) for e in events)):
+    if isinstance(events, (str, Path, dict)) or not all(
+            isinstance(e, SpanEvent) for e in events):
         events = load_chrome_trace(events_or_trace)
     models: dict[str, DeviceModel] = {}
     for device, samples in device_samples(events, name=name).items():

@@ -48,6 +48,12 @@ def test_sharded_equals_single_device():
 mesh = jax.make_mesh((8,), ("data",))
 res = simulate_sharded(vol, cfg, 6000, mesh, n_lanes=256, seed=5)
 assert int(res.n_launched) == 6000
+from repro.telemetry import default_tracer
+events = list(default_tracer().events)
+sim = events[-1]
+assert (sim.name, sim.device, sim.parent_id) == ("simulate", "mesh", None)
+kids = [e.name for e in events if e.parent_id == sim.span_id]
+assert kids == ["simulate.compile", "simulate.execute"], kids
 bal = A.energy_balance(res)
 assert abs(bal["residue_frac"]) < 1e-4, bal
 diff = np.abs(np.asarray(res.energy) - np.asarray(ref.energy)).max()

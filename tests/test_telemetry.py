@@ -11,9 +11,16 @@ Contracts under test:
     the SimResult fields, deposited_w matches sum(energy) to fp
     accumulation order, detected_w matches sum(det_w), and
     lane_segments == steps * n_lanes.
+  * The flush counters count the updates that carry weight: none
+    beyond the lane-segments run, no more exitance updates than
+    photons, the same counts from both engines, none from a volume that
+    absorbs every photon.
   * The Tracer's span timeline round-trips through Chrome trace JSON
     and feeds ``loadbalance.fit_pilot`` as measured-throughput samples
     (the dispatch -> measure -> refit -> re-partition loop).
+  * The entry points record a span tree on ``time.time_ns()``'s clock
+    into the process tracer: a CLI call and a ``simulate_many`` call,
+    compile spans ending before execution starts.
   * The CLI surfaces the silent-loss warnings (timed-out weight,
     detector id-buffer overflow) and writes trace/metrics files that
     parse back into device models.
@@ -21,6 +28,7 @@ Contracts under test:
 
 import dataclasses
 import json
+import time
 
 import jax
 import numpy as np
@@ -33,9 +41,11 @@ from repro.core import volume as V
 from repro.core.multidevice import ChunkScheduler, ElasticSimulator
 from repro.detectors import Detector
 from repro.launch import simulate as CLI
+from repro.scenarios import CompileCache, Scenario, simulate_many
 from repro.telemetry import (InMemorySink, JsonlSink, RoundStats, SpanEvent,
-                             Tracer, chrome_trace, device_label,
-                             fit_device_models, load_chrome_trace)
+                             Tracer, chrome_trace, default_tracer,
+                             device_label, fit_device_models,
+                             load_chrome_trace)
 
 SHAPE = (16, 16, 16)
 N_PHOTONS = 2000
@@ -113,6 +123,51 @@ def test_round_stats_engine_and_k_invariant_live_segments():
                                       collect_stats=True)
             vals.add(float(_run(vol, cfg, engine).stats.live_segments))
     assert len(vals) == 1, vals
+
+
+@pytest.mark.parametrize("engine", ["jnp", "pallas"])
+def test_flush_counters_count_weighted_updates(engine):
+    vol, cfg = _bench(reflect=True)
+    cfg = dataclasses.replace(cfg, steps_per_round=4, collect_stats=True)
+    res = _run(vol, cfg, engine)
+    st = res.stats
+    assert 0 < float(st.dep_live) <= float(st.live_segments)
+    assert float(st.live_segments) <= float(st.lane_segments)
+    # a photon leaves the z=0 face at most once
+    assert 0 < float(st.exit_live) <= int(res.n_launched)
+
+
+def test_flush_counters_engine_and_k_invariant():
+    """Trajectories are identical across engines and K, so are the
+    segments that deposit and that leave through the z=0 face."""
+    vol, cfg0 = _bench(reflect=True)
+    vals = set()
+    for engine in ("jnp", "pallas"):
+        for k in (1, 4):
+            cfg = dataclasses.replace(cfg0, steps_per_round=k,
+                                      collect_stats=True)
+            st = _run(vol, cfg, engine).stats
+            vals.add((float(st.dep_live), float(st.exit_live)))
+    assert len(vals) == 1, vals
+
+
+@pytest.mark.parametrize("engine", ["jnp", "pallas"])
+def test_flush_counters_all_absorbing(engine):
+    """No scattering and a high absorption: every photon flies along +z
+    and its weight is absorbed on the way, so nothing leaves the z=0
+    face."""
+    vol = V.homogeneous_cube(SHAPE, V.Medium(mua=10.0, mus=0.0, g=0.0,
+                                             n=1.0), 1.0)
+    cfg = V.SimConfig(do_reflect=False, collect_stats=True)
+    res = _run(vol, cfg, engine, source={"type": "pencil",
+                                         "pos": [8.0, 8.0, 0.0]})
+    bal = A.energy_balance(res)
+    # absorbed up to the Russian-roulette residue; nothing escapes
+    np.testing.assert_allclose(bal["absorbed"], bal["launched"], rtol=1e-3)
+    assert bal["escaped"] == 0.0
+    assert float(res.stats.exit_live) == 0.0
+    assert float(np.asarray(res.exitance).sum()) == 0.0
+    assert 0 < float(res.stats.dep_live) <= float(res.stats.live_segments)
 
 
 def test_round_stats_detected_w_reconciles():
@@ -318,7 +373,92 @@ def test_cli_trace_metrics_feed_load_balancer(tmp_path, capsys):
     assert sum(part) == 4000
     recs = [json.loads(line)
             for line in metrics.read_text().splitlines()]
-    assert any(r["type"] == "span" for r in recs)
+    spans = [r for r in recs if r["type"] == "span"]
+    root = next(r for r in spans if r["name"] == "cli.main")
+    assert root["parent_id"] is None
+    # every span of the call, chunks included, belongs to its root
+    assert all(r["root_id"] == root["span_id"] for r in spans)
+    assert {"cli.prepare", "chunk", "cli.summarize"} <= \
+        {r["name"] for r in spans}
     names = {r["name"] for r in recs if r["type"] == "counter"}
-    assert "photons_per_s" in names
     assert "round_stats.lane_occupancy" in names
+    # an unchunked call runs the program once, in simulate.execute
+    one = tmp_path / "one.jsonl"
+    CLI.main(_CLI_BASE + ["--metrics-out", str(one)])
+    by_name = {r["name"]: r for r in map(json.loads,
+                                         one.read_text().splitlines())
+               if r["type"] == "span"}
+    execute = by_name["simulate.execute"]
+    assert execute["parent_id"] == by_name["simulate"]["span_id"]
+    assert execute["root_id"] == by_name["cli.main"]["span_id"]
+    assert execute["photons"] == 800
+
+
+# ---------------------------------------------------------------------------
+# The span tree of the entry points, in the process tracer
+# ---------------------------------------------------------------------------
+
+def _tree_of_last_call(t_start_ns, t_end_ns):
+    """The spans of the process tracer's last root call, checked for a
+    well-formed tree inside the call's wall interval; name -> spans."""
+    events = list(default_tracer().events)
+    root = events[-1]
+    assert root.parent_id is None and root.root_id == root.span_id
+    spans = [e for e in events if e.root_id == root.span_id]
+    by_id = {e.span_id: e for e in spans}
+    for e in spans:
+        assert t_start_ns <= e.t0 * 1e9 and \
+            (e.t0 + e.dur) * 1e9 <= t_end_ns + 1e3, e.name
+        if e is root:
+            continue
+        parent = by_id[e.parent_id]
+        # children lie inside their parents (1 us of float rounding)
+        assert parent.t0 - 1e-6 <= e.t0, e.name
+        assert e.t0 + e.dur <= parent.t0 + parent.dur + 1e-6, e.name
+    out = {}
+    for e in spans:
+        out.setdefault(e.name, []).append(e)
+    return root, out
+
+
+def test_cli_call_span_tree():
+    t0 = time.time_ns()
+    CLI.main(_CLI_BASE + ["--collect-stats"])
+    root, spans = _tree_of_last_call(t0, time.time_ns())
+    assert root.name == "cli.main" and root.args["photons"] == 800
+    children = {e.name for es in spans.values() for e in es
+                if e.parent_id == root.span_id}
+    assert children == {"cli.prepare", "simulate", "cli.summarize"}
+    (sim,) = spans["simulate"]
+    (compile_,) = spans["simulate.compile"]
+    (execute,) = spans["simulate.execute"]
+    assert compile_.parent_id == execute.parent_id == sim.span_id
+    assert compile_.t0 + compile_.dur <= execute.t0 + 1e-6
+    assert execute.args["photons"] == 800
+
+
+def test_simulate_many_span_tree():
+    vol = V.benchmark_b1((8, 8, 8))
+    scs = [Scenario(vol, V.SimConfig(), 300, seed=s) for s in range(3)]
+    cache = CompileCache()
+    for _ in range(2):   # a cache miss, then a hit
+        t0 = time.time_ns()
+        simulate_many(scs, n_lanes=64, cache=cache)
+        root, spans = _tree_of_last_call(t0, time.time_ns())
+        assert root.name == "scenarios.simulate_many"
+        assert root.args["scenarios"] == 3
+        children = [e.name for es in spans.values() for e in es
+                    if e.parent_id == root.span_id]
+        assert sorted(children) == ["scenarios.batch", "scenarios.prepare",
+                                    "scenarios.split"]
+        (batch,) = spans["scenarios.batch"]
+        (execute,) = spans["scenarios.execute"]
+        assert execute.parent_id == batch.span_id
+        if batch.args["cache_hit"]:
+            assert "scenarios.compile" not in spans
+        else:
+            (compile_,) = spans["scenarios.compile"]
+            assert compile_.parent_id == batch.span_id
+            # compilation alone: it ends before the execution starts
+            assert compile_.t0 + compile_.dur <= execute.t0 + 1e-6
+    assert cache.hits == cache.misses == 1

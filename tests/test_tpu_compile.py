@@ -17,6 +17,7 @@ makes it lower has to remove the marker.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +92,17 @@ def _compile(fn, *args):
     return compiled
 
 
+ENGINE_SCOPES = {"regen", "step", "step.lookup", "step.boundary",
+                 "flush.fluence", "flush.exitance"}
+
+
+def _scopes(compiled) -> set[str]:
+    """The named scopes in the op_name metadata of compiled HLO."""
+    return {part for op in re.findall(r'op_name="([^"]*)"',
+                                      compiled.as_text())
+            for part in op.split("/")}
+
+
 def test_b1_engine_compiles(one_chip):
     vol, cfg = V.benchmark_b1((SIZE,) * 3), V.b1_config()
     raw = S.build_sim_fn(vol.shape, vol.unitinmm, cfg, LANES,
@@ -100,6 +112,7 @@ def test_b1_engine_compiles(one_chip):
                  _spec((), jnp.uint32, one_chip))
     mem = c.memory_analysis()
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2**30
+    assert ENGINE_SCOPES <= _scopes(c)
 
 
 def test_b2_detector_gates_record_compiles(one_chip):
@@ -114,6 +127,7 @@ def test_b2_detector_gates_record_compiles(one_chip):
     # outputs; both fit one chip's 16 GB many times over
     assert c.memory_analysis().output_size_in_bytes >= \
         SIZE**3 * GATES * 4 + RECORD_CAP * 16
+    assert ENGINE_SCOPES | {"capture", "record"} <= _scopes(c)
 
 
 def test_simulate_many_batched_closure_compiles(one_chip):
@@ -123,7 +137,8 @@ def test_simulate_many_batched_closure_compiles(one_chip):
     fn, args = SC.make_batched(scs, n_lanes=LANES)
     specs = jax.tree_util.tree_map(
         lambda a: _spec(np.shape(a), a.dtype, one_chip), args)
-    _compile(fn, *specs)
+    # the vmapped campaign loop inherits the engine's scopes
+    assert ENGINE_SCOPES <= _scopes(_compile(fn, *specs))
 
 
 def test_replay_two_pass_program_compiles(one_chip):
@@ -150,6 +165,7 @@ def test_sharded_b2_compiles_for_four_chips_with_one_collective_kind(topo):
                         _spec((), jnp.uint32, repl)).compile()
     kinds = collective_kinds(compiled.as_text())
     assert set(kinds) == {"all-reduce"}, kinds
+    assert ENGINE_SCOPES | {"psum"} <= _scopes(compiled)
 
 
 PALLAS_REFUSALS = (
