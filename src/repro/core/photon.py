@@ -68,6 +68,13 @@ class PhotonState(NamedTuple):
     #                      grazing rays can land on a wall where the crossing-
     #                      axis nudge is below fp32 resolution, freezing
     #                      floor(pos) and the photon with it.
+    label: jnp.ndarray   # (N,)  int32 medium label of ``ivox``.  Invariant,
+    #                      for every lane, dead or alive:
+    #                      label == labels_flat[flat(clip(ivox))].  ``step``
+    #                      reads it instead of gathering the label grid at
+    #                      the current voxel, and takes it over from the
+    #                      next-voxel gather when ``ivox`` advances; launch
+    #                      supplies it for fresh photons (DESIGN.md §rounds).
     w: jnp.ndarray       # (N,)  float32 packet weight
     s_left: jnp.ndarray  # (N,)  float32 remaining dimensionless scat. length
     t: jnp.ndarray       # (N,)  float32 elapsed time, ns
@@ -91,7 +98,24 @@ class StepResult(NamedTuple):
     #                       from the statistical roulette residue)
 
 
-def launch(pos, direc, w0, rng, active, shape) -> PhotonState:
+def _clamp_to_domain(pos, shape):
+    """Clamp positions onto ``[0, shape]``; return them and their voxel."""
+    pos = jnp.clip(jnp.asarray(pos, jnp.float32), 0.0,
+                   jnp.asarray(shape, jnp.float32))
+    bounds = jnp.asarray(shape, jnp.int32) - 1
+    return pos, jnp.clip(jnp.floor(pos).astype(jnp.int32), 0, bounds)
+
+
+def point_label(labels_flat, shape, point):
+    """Medium label of the voxel a launch at ``point`` starts in: the one
+    starting label of every photon of a point source
+    (``sources.launch_point``)."""
+    label, _ = _lookup_label(labels_flat, shape,
+                             _clamp_to_domain(point, shape)[1])
+    return label.astype(jnp.int32)
+
+
+def launch(pos, direc, w0, rng, active, shape, labels) -> PhotonState:
     """Assemble fresh photons from per-lane source samples.
 
     ``pos``/``direc``/``w0``/``rng`` come from a source's
@@ -99,6 +123,11 @@ def launch(pos, direc, w0, rng, active, shape) -> PhotonState:
     unit directions, initial packet weights, and the counter-seeded
     in-flight RNG state.  ``active`` masks lanes that have no photon to
     simulate.
+
+    ``labels`` gives each photon its starting medium label: the flat
+    label grid, read at each lane's voxel, or a scalar, the label that
+    every lane of a point source starts with (:func:`point_label`),
+    which spares the gather over the lanes.
 
     Sources are expected to lie within the domain; a sampled position
     outside ``[0, shape]`` (e.g. the tail of a wide Gaussian beam, or a
@@ -109,16 +138,18 @@ def launch(pos, direc, w0, rng, active, shape) -> PhotonState:
     in-domain sources (including ones sitting exactly on a face, like
     the default pencil) both clamps are no-ops.
     """
-    pos = jnp.clip(jnp.asarray(pos, jnp.float32), 0.0,
-                   jnp.asarray(shape, jnp.float32))
+    pos, ivox = _clamp_to_domain(pos, shape)
     direc = jnp.asarray(direc, jnp.float32)
     n = pos.shape[0]
-    bounds = jnp.asarray(shape, jnp.int32) - 1
-    ivox = jnp.clip(jnp.floor(pos).astype(jnp.int32), 0, bounds)
+    if jnp.ndim(labels) == 0:
+        label = jnp.broadcast_to(labels, (n,))
+    else:
+        label, _ = _lookup_label(labels, shape, ivox)
     return PhotonState(
         pos=pos,
         dir=direc,
         ivox=ivox,
+        label=label.astype(jnp.int32),
         w=jnp.where(active, w0, 0.0).astype(jnp.float32),
         s_left=jnp.zeros((n,), jnp.float32),
         t=jnp.zeros((n,), jnp.float32),
@@ -265,13 +296,14 @@ def step(state, labels_flat, media, shape, unitinmm, cfg: SimConfig) -> StepResu
     (reflection machinery always live, driven by traced flags) is
     compiled, mirroring the paper's unsimplified baseline kernel.
     """
-    pos, direc, ivox, w, s_left, t, rstate, alive = state
+    pos, direc, ivox, label, w, s_left, t, rstate, alive = state
     unitinmm = jnp.float32(unitinmm)
     nx, ny, nz = shape
 
     with jax.named_scope("step.lookup"):
-        label, _ = _lookup_label(labels_flat, shape, ivox)
-        props = jnp.take(media, label.astype(jnp.int32), axis=0)  # (N, 4)
+        # the current voxel's label is carried in the state; the label
+        # grid is read once a segment, at the next voxel (step.boundary)
+        props = jnp.take(media, label, axis=0)  # (N, 4)
     mua = props[:, 0] * unitinmm
     mus = props[:, 1] * unitinmm
     g = props[:, 2]
@@ -386,6 +418,10 @@ def step(state, labels_flat, media, shape, unitinmm, cfg: SimConfig) -> StepResu
         # advance the authoritative voxel index on transmitting crossings
         advances = crossing & ~reflects & ~escapes
         new_ivox = jnp.where(advances[:, None], next_vox, ivox)
+        # ``advances`` excludes ``oob``, so ``next_label`` is the grid's
+        # label at ``next_vox`` wherever it is taken
+        new_label = jnp.where(advances, next_label.astype(jnp.int32),
+                              label)
 
     # --- ROULETTE + time gate ---
     alive_after = alive & ~escapes
@@ -407,6 +443,7 @@ def step(state, labels_flat, media, shape, unitinmm, cfg: SimConfig) -> StepResu
         pos=jnp.where(alive[:, None], new_pos, pos),
         dir=jnp.where(alive[:, None], new_dir, direc),
         ivox=jnp.where(alive[:, None], new_ivox, ivox),
+        label=new_label,
         w=jnp.where(alive, w_final, w),
         s_left=jnp.where(alive, s_left, state.s_left),
         t=jnp.where(alive, t_new, t),
@@ -420,7 +457,7 @@ def step(state, labels_flat, media, shape, unitinmm, cfg: SimConfig) -> StepResu
         esc_w=jnp.where(alive, esc_w, 0.0),
         esc_pos=esc_pos,
         dep_t=t_new,
-        seg_med=label.astype(jnp.int32),
+        seg_med=label,
         seg_len=jnp.where(alive, seg * unitinmm, 0.0),
         timed_w=jnp.where(alive, timed_w, 0.0),
     )

@@ -46,7 +46,7 @@ from repro.core.volume import SimConfig, Source, Volume
 from repro.detectors import (Detector, accumulate_capture, as_detectors,
                              det_geometry, update_capture,
                              validate_detectors)
-from repro.sources import PhotonSource, as_source
+from repro.sources import PhotonSource, as_source, launch_point
 from repro.telemetry.stats import RoundStats
 from repro.telemetry.trace import default_tracer
 
@@ -155,7 +155,8 @@ def lane_sum(x):
 
 
 def _regenerate(state, remaining, launched_per_lane, next_id, quota,
-                source, seed, mode, shape, ppath=None, lane_ids=None):
+                source, seed, mode, shape, launch_labels, ppath=None,
+                lane_ids=None):
     """Relaunch photons in dead lanes according to the workload mode.
 
     ``next_id`` is the 64-bit global photon id counter as a ``(lo, hi)``
@@ -165,6 +166,10 @@ def _regenerate(state, remaining, launched_per_lane, next_id, quota,
     RNG streams instead of wrapping (DESIGN.md §replay).  Ids below
     2**32 produce bit-identical launch states to the historical 32-bit
     counter.
+
+    ``launch_labels`` gives fresh photons their starting medium label
+    (``photon.launch``): the flat label grid, gathered over the lanes,
+    or the one label of a point source's voxel.
 
     ``ppath`` (detector runs only) is the per-lane partial-pathlength
     accumulator; relaunched lanes start their new photon with zeroed
@@ -191,7 +196,7 @@ def _regenerate(state, remaining, launched_per_lane, next_id, quota,
         jnp.uint32)
     ids = xrng.PhotonId(lo=ids_lo, hi=ids_hi)
     pos, direc, w0, rng = source.sample(ids, seed)
-    fresh = ph.launch(pos, direc, w0, rng, relaunch, shape)
+    fresh = ph.launch(pos, direc, w0, rng, relaunch, shape, launch_labels)
 
     def merge(new, old):
         mask = relaunch
@@ -221,7 +226,8 @@ def _regenerate(state, remaining, launched_per_lane, next_id, quota,
 
 @jax.named_scope("regen")
 def _maybe_regenerate(state, remaining, launched_per_lane, next_id, quota,
-                      source, seed, mode, shape, ppath=None, lane_ids=None):
+                      source, seed, mode, shape, launch_labels, ppath=None,
+                      lane_ids=None):
     """Regenerate only when some lane will actually relaunch.
 
     The full regeneration path costs two prefix-sums plus a
@@ -242,8 +248,8 @@ def _maybe_regenerate(state, remaining, launched_per_lane, next_id, quota,
 
     def do(_):
         return _regenerate(state, remaining, launched_per_lane, next_pair,
-                           quota, source, seed, mode, shape, ppath,
-                           lane_ids)
+                           quota, source, seed, mode, shape, launch_labels,
+                           ppath, lane_ids)
 
     def skip(_):
         out = (state, remaining, launched_per_lane, next_pair,
@@ -377,6 +383,11 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
         lane_idx = jnp.arange(n_lanes, dtype=jnp.int32)
         quota = n_photons // n_lanes + (lane_idx < n_photons % n_lanes)
         n_media = media.shape[0]
+        # fresh photons' medium label: for a point source one lookup,
+        # made here once instead of in every round that relaunches
+        point = launch_point(source)
+        launch_labels = (labels_flat if point is None
+                         else ph.point_label(labels_flat, shape, point))
         # partial pathlengths are only tracked when a detector can consume
         # them; width-0 otherwise so the carry structure stays fixed
         ppath_w = n_media if n_det else 0
@@ -385,6 +396,8 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
             pos=jnp.zeros((n_lanes, 3), jnp.float32),
             dir=jnp.tile(jnp.asarray([0.0, 0.0, 1.0], jnp.float32), (n_lanes, 1)),
             ivox=jnp.zeros((n_lanes, 3), jnp.int32),
+            # the label of voxel (0, 0, 0), which ivox = 0 names
+            label=jnp.full((n_lanes,), labels_flat[0], jnp.int32),
             w=jnp.zeros((n_lanes,), jnp.float32),
             s_left=jnp.zeros((n_lanes,), jnp.float32),
             t=jnp.zeros((n_lanes,), jnp.float32),
@@ -514,16 +527,18 @@ def build_sim_fn(shape: tuple[int, int, int], unitinmm: float,
                 (state, remaining, launched, next_id, w_new, ppath,
                  lane_ids) = _maybe_regenerate(
                     c.state, c.remaining, c.launched_per_lane, next_pair,
-                    quota, source, seed, mode, shape, c.ppath, c.lane_ids)
+                    quota, source, seed, mode, shape, launch_labels,
+                    c.ppath, c.lane_ids)
             elif n_det:
                 (state, remaining, launched, next_id, w_new,
                  ppath) = _maybe_regenerate(
                     c.state, c.remaining, c.launched_per_lane, next_pair,
-                    quota, source, seed, mode, shape, c.ppath)
+                    quota, source, seed, mode, shape, launch_labels,
+                    c.ppath)
             else:
                 state, remaining, launched, next_id, w_new = _maybe_regenerate(
                     c.state, c.remaining, c.launched_per_lane, next_pair,
-                    quota, source, seed, mode, shape)
+                    quota, source, seed, mode, shape, launch_labels)
                 ppath = c.ppath
             capd = capg = None
             live = dep_sum = det_new = dep_live = exit_live = None

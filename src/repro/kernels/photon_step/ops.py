@@ -87,7 +87,8 @@ def simulate_kernel(volume: Volume, cfg: SimConfig, n_photons: int,
         jax.numpy.uint32))
     pos, direc, w0, rng = source.sample(ids, jax.numpy.uint32(seed))
     state = ph.launch(pos, direc, w0, rng,
-                      jax.numpy.ones((n_photons,), bool), volume.shape)
+                      jax.numpy.ones((n_photons,), bool), volume.shape,
+                      volume.labels.reshape(-1))
     ppath = det_geom = None
     if dets:
         validate_detectors(dets, volume.shape)

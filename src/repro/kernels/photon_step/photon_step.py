@@ -116,13 +116,13 @@ def resolve_block_lanes(n_lanes: int, block_lanes: int) -> int:
 def _kernel(labels_ref, media_ref, *refs,
             shape, unitinmm, cfg: SimConfig, n_steps: int, n_det: int,
             record: bool, jac_cols: int, stats: bool):
-    # unpack the variadic refs: 8 state inputs [+ ppath + det_geom]
-    # [+ jac_w + jac_col], then 8 state outputs + fluence/exitance/esc/
+    # unpack the variadic refs: 9 state inputs [+ ppath + det_geom]
+    # [+ jac_w + jac_col], then 9 state outputs + fluence/exitance/esc/
     # timed [+ ppath + det_w + det_ppath] [+ cap_det + cap_gate]
     # [+ jac] [+ stats] — assembled to match photon_step_pallas's specs
-    (pos_ref, dir_ref, ivox_ref, w_ref, s_ref, t_ref, rng_ref,
-     alive_ref) = refs[:8]
-    cur = 8
+    (pos_ref, dir_ref, ivox_ref, label_ref, w_ref, s_ref, t_ref, rng_ref,
+     alive_ref) = refs[:9]
+    cur = 9
     if n_det:
         ppath_ref, det_geom_ref = refs[cur:cur + 2]
         cur += 2
@@ -130,9 +130,9 @@ def _kernel(labels_ref, media_ref, *refs,
         jac_w_ref, jac_col_ref = refs[cur:cur + 2]
         cur += 2
     outs = refs[cur:]
-    (out_pos, out_dir, out_ivox, out_w, out_s, out_t, out_rng,
-     out_alive, fluence_ref, exitance_ref, esc_ref, timed_ref) = outs[:12]
-    cur = 12
+    (out_pos, out_dir, out_ivox, out_label, out_w, out_s, out_t, out_rng,
+     out_alive, fluence_ref, exitance_ref, esc_ref, timed_ref) = outs[:13]
+    cur = 13
     if n_det:
         out_ppath, det_w_ref, det_ppath_ref = outs[cur:cur + 3]
         cur += 3
@@ -162,8 +162,8 @@ def _kernel(labels_ref, media_ref, *refs,
     media = media_ref[...]
     state = ph.PhotonState(
         pos=pos_ref[...], dir=dir_ref[...], ivox=ivox_ref[...],
-        w=w_ref[...], s_left=s_ref[...], t=t_ref[...], rng=rng_ref[...],
-        alive=alive_ref[...] != 0,
+        label=label_ref[...], w=w_ref[...], s_left=s_ref[...],
+        t=t_ref[...], rng=rng_ref[...], alive=alive_ref[...] != 0,
     )
     n = state.w.shape[0]
     if n_det:
@@ -241,6 +241,7 @@ def _kernel(labels_ref, media_ref, *refs,
     out_pos[...] = state.pos
     out_dir[...] = state.dir
     out_ivox[...] = state.ivox
+    out_label[...] = state.label
     out_w[...] = state.w
     out_s[...] = state.s_left
     out_t[...] = state.t
@@ -357,6 +358,7 @@ def photon_step_pallas(labels_flat, media, state: ph.PhotonState,
         jax.ShapeDtypeStruct((n, 3), jnp.float32),   # pos
         jax.ShapeDtypeStruct((n, 3), jnp.float32),   # dir
         jax.ShapeDtypeStruct((n, 3), jnp.int32),     # ivox
+        jax.ShapeDtypeStruct((n,), jnp.int32),       # label
         jax.ShapeDtypeStruct((n,), jnp.float32),     # w
         jax.ShapeDtypeStruct((n,), jnp.float32),     # s_left
         jax.ShapeDtypeStruct((n,), jnp.float32),     # t
@@ -368,19 +370,20 @@ def photon_step_pallas(labels_flat, media, state: ph.PhotonState,
         jax.ShapeDtypeStruct((n,), jnp.float32),     # timed-out weight
     ]
     out_specs = [
-        lane_spec((3,)), lane_spec((3,)), lane_spec((3,)),
+        lane_spec((3,)), lane_spec((3,)), lane_spec((3,)), lane_spec(),
         lane_spec(), lane_spec(), lane_spec(),
         lane_spec((4,)), lane_spec(),
         full_vol, full_img, lane_spec(), lane_spec(),
     ]
     in_specs = [
         full_spec(nvox), full_media,
-        lane_spec((3,)), lane_spec((3,)), lane_spec((3,)),
+        lane_spec((3,)), lane_spec((3,)), lane_spec((3,)), lane_spec(),
         lane_spec(), lane_spec(), lane_spec(),
         lane_spec((4,)), lane_spec(),
     ]
     operands = [labels_flat, media,
-                state.pos, state.dir, state.ivox, state.w, state.s_left,
+                state.pos, state.dir, state.ivox, state.label, state.w,
+                state.s_left,
                 state.t, state.rng, state.alive.astype(jnp.int8)]
     if n_det:
         in_specs += [lane_spec((n_media,)), full_spec(n_det, 3)]
@@ -428,7 +431,7 @@ def photon_step_pallas(labels_flat, media, state: ph.PhotonState,
         n_det, record, jac_cols, stats, packed_state=False), \
         "pallas output list drifted from kernels/photon_step/spec.py"
     new_state = ph.PhotonState(
-        pos=outs[0], dir=outs[1], ivox=outs[2], w=outs[3], s_left=outs[4],
-        t=outs[5], rng=outs[6], alive=outs[7] != 0,
+        pos=outs[0], dir=outs[1], ivox=outs[2], label=outs[3], w=outs[4],
+        s_left=outs[5], t=outs[6], rng=outs[7], alive=outs[8] != 0,
     )
-    return (new_state,) + tuple(outs[8:])
+    return (new_state,) + tuple(outs[9:])

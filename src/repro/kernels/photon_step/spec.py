@@ -31,7 +31,8 @@ from __future__ import annotations
 
 # The photon state, packed as one PhotonState in the oracle/engine and
 # unpacked into one array per field by the Pallas kernel (lane-blocked).
-STATE_FIELDS = ("pos", "dir", "ivox", "w", "s_left", "t", "rng", "alive")
+STATE_FIELDS = ("pos", "dir", "ivox", "label", "w", "s_left", "t", "rng",
+                "alive")
 
 # Unconditional outputs that follow the state in every mirror.
 BASE_OUTPUTS = ("fluence", "exitance", "escaped", "timed")
@@ -59,8 +60,8 @@ EXT_PARAMS = ("ppath", "det_geom", "record", "jac_w", "jac_col",
               "jac_cols", "stats")
 
 # Bytes per lane of photon state: pos/dir (3 f32 each), ivox (3 i32),
-# w/s_left/t (f32), rng (4 u32), alive (i8).
-STATE_LANE_BYTES = 65
+# label (i32), w/s_left/t (f32), rng (4 u32), alive (i8).
+STATE_LANE_BYTES = 69
 
 
 def output_arity(n_det: int = 0, record: bool = False, jac_cols: int = 0,

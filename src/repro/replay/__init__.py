@@ -207,7 +207,8 @@ def _build_replay_fn(shape, unitinmm, cfg: SimConfig, n_lanes: int,
         # the Pallas capture path threads the per-lane ppath state; the
         # jnp pass reads none of it, so it carries a width-0 placeholder
         pp_w = n_media if engine == "pallas" else 0
-        carry_a = (ph.launch(pos, direc, w0, rng, active, shape),
+        carry_a = (ph.launch(pos, direc, w0, rng, active, shape,
+                             labels_flat),
                    jnp.zeros((n_lanes,), jnp.float32),
                    jnp.full((n_lanes,), -1, jnp.int32),
                    jnp.full((n_lanes,), -1, jnp.int32),
@@ -247,7 +248,8 @@ def _build_replay_fn(shape, unitinmm, cfg: SimConfig, n_lanes: int,
 
         _, jac, _ = jax.lax.while_loop(
             cond, body_b,
-            (ph.launch(pos, direc, w0, rng, active, shape),
+            (ph.launch(pos, direc, w0, rng, active, shape,
+                       labels_flat),
              jnp.zeros((nvox * jac_cols,), jnp.float32),
              jnp.int32(0)))
         return jac, w_exit, gate, rdet

@@ -21,6 +21,7 @@ from repro.sources.base import (
     flight_stream,
     from_dict,
     get_source_cls,
+    launch_point,
     launch_stream,
     register,
     stage_source,
@@ -47,6 +48,7 @@ __all__ = [
     "flight_stream",
     "from_dict",
     "get_source_cls",
+    "launch_point",
     "launch_stream",
     "register",
     "stage_source",

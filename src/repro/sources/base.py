@@ -28,6 +28,11 @@ Determinism contract (DESIGN.md §sources):
   * Each source type consumes a fixed number of launch-stream uniforms
     per photon (``N_DRAWS``), independent of runtime values.
 
+A type whose photons all start at its staged ``pos`` declares
+``POINT = True`` (pencil, isotropic, cone): ``launch_point`` gives that
+position, and the engine looks up the starting medium label there once
+(``photon.point_label``) instead of once per lane at every launch.
+
 Together with the counter-based seeding this makes every source
 bit-reproducible across single-device, shard_map multi-device
 (``id_offset`` ranges), chunked, and restarted runs: photon ``k`` gets
@@ -176,6 +181,23 @@ class StagedSource:
 
     def sample(self, photon_ids, seed):
         return self.source_cls.sample_staged(self.staged, photon_ids, seed)
+
+
+def launch_point(source):
+    """The one launch position every photon of a point source shares.
+
+    Returns the staged ``pos`` (a traced per-scenario value under
+    scenario batching) when the source's type declares ``POINT``, else
+    ``None``: each lane's starting voxel may then differ and is looked
+    up on its own (``photon.launch``).
+    """
+    if isinstance(source, StagedSource):
+        cls, staged = source.source_cls, source.staged
+    else:
+        cls, staged = type(source), None
+    if not getattr(cls, "POINT", False):
+        return None
+    return (source.stage() if staged is None else staged)["pos"]
 
 
 def stage_source(source) -> tuple[type, dict]:

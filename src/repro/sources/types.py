@@ -62,6 +62,7 @@ class Pencil:
     dir: Vec3 = (0.0, 0.0, 1.0)
 
     N_DRAWS = 0
+    POINT = True  # every photon starts at the staged ``pos``
 
     def stage(self):
         return {"pos": jnp.asarray(self.pos, jnp.float32),
@@ -86,6 +87,7 @@ class IsotropicPoint:
     pos: Vec3 = (30.0, 30.0, 30.0)
 
     N_DRAWS = 2  # u_cos, u_phi
+    POINT = True  # every photon starts at the staged ``pos``
 
     def stage(self):
         return {"pos": jnp.asarray(self.pos, jnp.float32)}
@@ -115,6 +117,7 @@ class Cone:
     half_angle_deg: float = 15.0
 
     N_DRAWS = 2  # u_cos, u_phi
+    POINT = True  # every photon starts at the staged ``pos``
 
     def stage(self):
         e1, e2 = base.orthonormal_frame(self.dir)

@@ -61,6 +61,7 @@ def _seed_sim_fn(shape, unitinmm, cfg, n_lanes, mode="dynamic", source=None):
             dir=jnp.tile(jnp.asarray([0.0, 0.0, 1.0], jnp.float32),
                          (n_lanes, 1)),
             ivox=jnp.zeros((n_lanes, 3), jnp.int32),
+            label=jnp.full((n_lanes,), labels_flat[0], jnp.int32),
             w=jnp.zeros((n_lanes,), jnp.float32),
             s_left=jnp.zeros((n_lanes,), jnp.float32),
             t=jnp.zeros((n_lanes,), jnp.float32),
@@ -89,7 +90,7 @@ def _seed_sim_fn(shape, unitinmm, cfg, n_lanes, mode="dynamic", source=None):
             # engine's int32 counter, so the copy keeps its contract
             state, remaining, launched, next_id, w_new = S._regenerate(
                 c.state, c.remaining, c.launched_per_lane, c.next_id,
-                quota, source, seed, mode, shape)
+                quota, source, seed, mode, shape, labels_flat)
             res = ph.step(state, labels_flat, media, shape, unitinmm, cfg)
             energy = c.energy.at[res.dep_idx].add(res.dep_w)
             # the engine sums lanes in a fixed order (S.lane_sum)

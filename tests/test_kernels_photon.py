@@ -23,7 +23,8 @@ def _mk_state(n, vol, seed=7):
     src = SRC.Pencil(pos=(vol.shape[0] / 2, vol.shape[1] / 2, 0.0))
     ids = jnp.arange(n, dtype=jnp.uint32)
     pos, direc, w0, rng = src.sample(ids, jnp.uint32(seed))
-    return ph.launch(pos, direc, w0, rng, jnp.ones((n,), bool), vol.shape)
+    return ph.launch(pos, direc, w0, rng, jnp.ones((n,), bool), vol.shape,
+                     vol.labels.reshape(-1))
 
 
 @pytest.mark.parametrize("shape,n,block,steps,reflect", [

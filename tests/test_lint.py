@@ -74,7 +74,7 @@ _PALLAS_SRC = """\
                            record=False, jac_w=None, jac_col=None,
                            jac_cols=0, stats=False):
         n_det = 0 if det_geom is None else 1
-        out_shapes = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12]
+        out_shapes = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13]
         if n_det:
             out_shapes += [1, 2, 3]
         if record:
@@ -154,10 +154,10 @@ def test_mirror_catches_base_arity_drift(tmp_path):
     _mirror_tree(tmp_path)
     _write(tmp_path, "src/repro/kernels/photon_step/photon_step.py",
            _PALLAS_SRC.replace(
-               "out_shapes = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12]",
-               "out_shapes = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11]"))
+               "out_shapes = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13]",
+               "out_shapes = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12]"))
     rep = _lint(tmp_path, "REP101")
-    assert any("out_shapes` has 11 entries" in f.message
+    assert any("out_shapes` has 12 entries" in f.message
                for f in rep.findings)
 
 

@@ -138,7 +138,8 @@ def test_kernel_capture_records_match_oracle():
     src = Pencil(pos=(8.0, 8.0, 0.0))
     ids = jnp.arange(n, dtype=jnp.uint32)
     pos, direc, w0, rng = src.sample(ids, jnp.uint32(SEED))
-    state = ph.launch(pos, direc, w0, rng, jnp.ones((n,), bool), vol.shape)
+    state = ph.launch(pos, direc, w0, rng, jnp.ones((n,), bool), vol.shape,
+                      vol.labels.reshape(-1))
     dg = det_geometry(DETS)
     pp0 = jnp.zeros((n, vol.media.shape[0]), jnp.float32)
     args = (vol.labels.reshape(-1), vol.media, state, vol.shape,

@@ -201,7 +201,8 @@ def test_out_of_domain_launches_are_clamped():
                 SRC.GaussianBeam(pos=(8.0, 8.0, 0.0), waist=10.0)):
         ids = jnp.arange(400, dtype=jnp.uint32)
         pos, direc, w0, rng = src.sample(ids, jnp.uint32(2))
-        state = ph.launch(pos, direc, w0, rng, jnp.ones((400,), bool), SHAPE)
+        state = ph.launch(pos, direc, w0, rng, jnp.ones((400,), bool), SHAPE,
+                          vol.labels.reshape(-1))
         p = np.asarray(state.pos)
         assert p.min() >= 0.0 and np.all(p <= np.asarray(SHAPE, np.float32))
         np.testing.assert_array_equal(

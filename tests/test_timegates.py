@@ -71,6 +71,7 @@ def _pr2_fused_sim_fn(shape, unitinmm, cfg, n_lanes, mode="dynamic",
             dir=jnp.tile(jnp.asarray([0.0, 0.0, 1.0], jnp.float32),
                          (n_lanes, 1)),
             ivox=jnp.zeros((n_lanes, 3), jnp.int32),
+            label=jnp.full((n_lanes,), labels_flat[0], jnp.int32),
             w=jnp.zeros((n_lanes,), jnp.float32),
             s_left=jnp.zeros((n_lanes,), jnp.float32),
             t=jnp.zeros((n_lanes,), jnp.float32),
@@ -122,7 +123,7 @@ def _pr2_fused_sim_fn(shape, unitinmm, cfg, n_lanes, mode="dynamic",
         def body(c):
             state, remaining, launched, next_id, w_new = S._maybe_regenerate(
                 c.state, c.remaining, c.launched_per_lane, c.next_id,
-                quota, source, seed, mode, shape,
+                quota, source, seed, mode, shape, labels_flat,
             )
             state, dep_i, dep_w, ex_i, ex_w, esc = round_jnp(state)
             energy = c.energy.at[dep_i.reshape(-1)].add(dep_w.reshape(-1))

@@ -190,7 +190,8 @@ def test_pallas_photon_step_lowers_for_tpu(one_chip, block_lanes):
         return _spec((LANES,) + minor, dt, one_chip)
 
     state = ph.PhotonState(pos=lane(3), dir=lane(3),
-                           ivox=lane(3, dt=jnp.int32), w=lane(),
+                           ivox=lane(3, dt=jnp.int32),
+                           label=lane(dt=jnp.int32), w=lane(),
                            s_left=lane(), t=lane(),
                            rng=lane(4, dt=jnp.uint32),
                            alive=lane(dt=jnp.bool_))
